@@ -2,14 +2,13 @@
 
 Paper: FPGA accelerator vs CPU master process, Pong (F=6, D=9) and Gomoku
 (F=36, D=5), p in 8..128.  Here: batched-jit accelerator (+ wavefront
-beyond-paper variant + the arena-native Pallas kernels, interpret mode on
-this CPU-only container) vs the sequential CPU reference, on a single CPU
-core.  The simulation backend is a null stub so only in-tree time
+beyond-paper variant + the arena-native Pallas kernels) vs the sequential
+CPU reference.  The simulation backend is a null stub so only in-tree time
 (Selection + Expansion tree-half + BackUp + transfers + ST) is measured,
-exactly the paper's Fig. 4 metric.  The kernel numbers measure the
-serving path's executor dispatch, not TPU silicon — interpret mode runs
-the kernel as jit'd jax ops, so treat them as a correctness-carrying
-upper bound until a real TPU run flips kernels.ops.INTERPRET.
+exactly the paper's Fig. 4 metric.  The kernels run compiled on a TPU
+and in the Pallas interpreter on the CPU backend (kernels/ops.py picks by
+platform), so a CPU run's kernel numbers time the interpreter, not the
+chip.
 """
 
 from __future__ import annotations

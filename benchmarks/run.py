@@ -52,6 +52,8 @@ def main(argv=None) -> None:
                          "gate for the bench harness itself")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (
         bench_diversity, bench_fixedpoint, bench_intree, bench_resources,
         bench_roofline, bench_service, bench_throughput,

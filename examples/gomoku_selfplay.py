@@ -24,6 +24,7 @@ import numpy as np
 from repro.core import TreeConfig
 from repro.envs import GomokuEnv
 from repro.envs.policy_net import NNSimBackend, apply, init_params
+from repro.launch.compile_cache import use_compile_cache
 from repro.service import SearchClient, SearchRequest
 from repro.sim import CachedSimBackend, SimServer
 
@@ -84,6 +85,7 @@ def train_net(params, states, z, lr=1e-2, epochs=30):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--games", type=int, default=2)
     ap.add_argument("--p", type=int, default=8)

@@ -23,12 +23,14 @@ import jax
 
 from repro import configs
 from repro.core import TreeConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import lm
 from repro.service import SearchClient, SearchRequest
 from repro.sim import LMContinuationBackend, LMTreeEnv, SimServer
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--tokens", type=int, default=6)

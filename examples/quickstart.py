@@ -12,9 +12,11 @@ import numpy as np
 
 from repro.core import TreeConfig, TreeParallelMCTS, RolloutBackend
 from repro.envs import BanditTreeEnv
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     env = BanditTreeEnv(fanout=6, terminal_depth=12)
     cfg = TreeConfig(
         X=1024,          # node budget per MCTS step (tree-flush boundary)

@@ -68,6 +68,7 @@ import numpy as np
 
 from repro.core import TreeConfig
 from repro.envs import BanditTreeEnv, BanditValueBackend
+from repro.launch.compile_cache import use_compile_cache
 from repro.service import (
     POLICY_NAMES, SearchClient, SearchRequest, SearchService,
     ServiceFrontend,
@@ -218,6 +219,7 @@ def run_frontend(args):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--executor", choices=("faithful", "pallas"),
                     default="faithful",

@@ -13,6 +13,7 @@ import argparse
 
 import jax
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import lm, steps as msteps
 from repro.models.config import LayerSpec, ModelConfig, param_count
 from repro.data import Prefetcher, SyntheticTokens
@@ -30,6 +31,7 @@ CFG_100M = ModelConfig(
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=4)

@@ -43,6 +43,8 @@ replaces, and is bit-identical to it (pinned in tests/test_vector_env).
 
 from __future__ import annotations
 
+import os
+import pickle
 from typing import Protocol, runtime_checkable
 
 import numpy as np
@@ -99,6 +101,24 @@ _WORKER_ENV = None  # per-process env replica (set by the pool initializer)
 def _pool_init(env):
     global _WORKER_ENV
     _WORKER_ENV = env
+
+
+def _pool_worker_init(env_bytes: bytes):
+    """Process-pool initializer.  Pins the worker's JAX to the CPU backend
+    BEFORE the env replica is unpickled (an env may carry device arrays,
+    whose unpickling initialises a backend): the parent owns the
+    accelerator, and a chip belongs to one process at a time, so a
+    worker that reached for it would fail or hang."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError(
+            "a JAX backend was initialised in a host-expansion worker "
+            "before it could be pinned to the CPU")
+    jax.config.update("jax_platforms", "cpu")
+    _pool_init(pickle.loads(env_bytes))
 
 
 def _pool_step_chunk(payload):
@@ -170,10 +190,11 @@ class PoolVectorEnv:
             import multiprocessing
             from concurrent.futures import ProcessPoolExecutor
             # spawn, not fork: the parent typically has jax threads live,
-            # and forking a multithreaded process can deadlock
+            # and forking a multithreaded process can deadlock.  The env
+            # crosses as bytes so the worker pins JAX before unpickling it.
             self._pool = ProcessPoolExecutor(
-                max_workers=self.workers, initializer=_pool_init,
-                initargs=(self.env,),
+                max_workers=self.workers, initializer=_pool_worker_init,
+                initargs=(pickle.dumps(self.env),),
                 mp_context=multiprocessing.get_context("spawn"))
         return self._pool
 

@@ -7,6 +7,7 @@
   ref.py             — pure-jnp oracles (transitively bit-exact vs the
                        sequential CPU program)
 
-Kernels target the TPU backend and are validated with interpret=True on
-CPU (this container has no TPU).
+Kernels are compiled by Mosaic for TPU and run in the Pallas interpreter
+on the CPU backend; ops.py picks by the platform a program is lowered
+for.
 """

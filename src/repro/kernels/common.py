@@ -103,20 +103,28 @@ def lane_iota():
 
 
 def load_row(ref, row):
-    """One 128-lane row as [1, 128]."""
-    return pl.load(ref, (pl.dslice(canonical_index(row), 1), slice(None)))
+    """One row of a 2-D ref as [1, n] (a 128-lane row for packed arrays)."""
+    return ref[pl.ds(canonical_index(row), 1), :]
 
 
 def store_row(ref, row, val):
-    pl.store(ref, (pl.dslice(canonical_index(row), 1), slice(None)), val)
+    ref[pl.ds(canonical_index(row), 1), :] = val
+
+
+def extract_lane(vec, lane):
+    """vec[0, lane] for a [1, n] vector and a traced lane index.
+
+    A masked sum over the lanes, not a dynamic slice: Mosaic has no
+    lowering for ``dynamic_slice`` on a vector.  Exactly one lane is
+    kept and the rest are zero, so the sum is the lane's value bit for
+    bit (finite values)."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, vec.shape, 1) == lane
+    return jnp.sum(jnp.where(hit, vec, jnp.zeros_like(vec)), dtype=vec.dtype)
 
 
 def sload(ref, idx):
     """Scalar load from a packed node array."""
-    row = load_row(ref, idx // LANES)
-    return jax.lax.dynamic_slice(
-        row, (canonical_index(0), canonical_index(idx % LANES)), (1, 1)
-    )[0, 0]
+    return extract_lane(load_row(ref, idx // LANES), idx % LANES)
 
 
 def sadd(ref, idx, inc):
@@ -125,9 +133,3 @@ def sadd(ref, idx, inc):
     row = load_row(ref, row_i)
     upd = jnp.where(lane_iota() == (idx % LANES), inc, 0).astype(row.dtype)
     store_row(ref, row_i, row + upd)
-
-
-def extract_lane(vec_1x128, lane):
-    """vec[0, lane] for traced lane index."""
-    return jax.lax.dynamic_slice(
-        vec_1x128, (canonical_index(0), canonical_index(lane)), (1, 1))[0, 0]

@@ -51,10 +51,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, causal, window, scale,
         # go through canonical_index so the tuple stays one dtype under
         # JAX_ENABLE_X64.
         kstart = canonical_index(kj * blk_k)
-        k = pl.load(k_ref, (pl.dslice(canonical_index(0), 1),
-                            pl.dslice(kstart, blk_k), slice(None)))[0]
-        v = pl.load(v_ref, (pl.dslice(canonical_index(0), 1),
-                            pl.dslice(kstart, blk_k), slice(None)))[0]
+        k = k_ref[pl.ds(canonical_index(0), 1), pl.ds(kstart, blk_k), :][0]
+        v = v_ref[pl.ds(canonical_index(0), 1), pl.ds(kstart, blk_k), :][0]
         s = q @ k.astype(jnp.float32).T                      # [blk_q, blk_k]
         kpos = kj * blk_k + jax.lax.broadcasted_iota(
             jnp.int32, (blk_q, blk_k), 1)
@@ -85,8 +83,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, causal, window, scale,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "window", "blk_q", "blk_k", "interpret"))
-def flash_attention(q, k, v, *, causal=True, window=None,
-                    blk_q=128, blk_k=128, interpret=True):
+def flash_attention(q, k, v, *, interpret: bool, causal=True, window=None,
+                    blk_q=128, blk_k=128):
     """q: [B, Sq, H, dh]; k/v: [B, Sk, Hkv, dh] (H % Hkv == 0).
     Returns [B, Sq, H, dh].  Sq/Sk padded to block multiples internally."""
     B, Sq, H, dh = q.shape

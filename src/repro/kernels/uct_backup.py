@@ -71,7 +71,7 @@ def _backup_kernel(
     node_o_ref[...] = no_in_ref[...]
 
     def row_of(x):  # [1,p] ref scalar extraction
-        return lambda j: cm.extract_lane(pl.load(x, (slice(None), slice(None))), j)
+        return lambda j: cm.extract_lane(x[...], j)
 
     get_depth, get_leaf = row_of(depth_ref), row_of(leaf_ref)
     get_ea, get_sim, get_val = row_of(ea_ref), row_of(simn_ref), row_of(val_ref)
@@ -86,8 +86,8 @@ def _backup_kernel(
         sim_depth = depth + jnp.where(expanded, i32(1), i32(0))
 
         def level(d, _):
-            pn_row = pl.load(pn_ref, (pl.dslice(j, 1), slice(None)))
-            pa_row = pl.load(pa_ref, (pl.dslice(j, 1), slice(None)))
+            pn_row = cm.load_row(pn_ref, j)
+            pa_row = cm.load_row(pa_ref, j)
             node = cm.extract_lane(pn_row, d)
             a = cm.extract_lane(pa_row, d)
             on = (d < depth) & (node != NULL)
@@ -140,7 +140,7 @@ def _backup_kernel(
                    static_argnames=("cfg", "p", "alternating", "interpret"))
 def backup_arena(cfg: TreeConfig, arena, active, pn, pa, depths, leaves,
                  expand_action, sim_nodes, values_fx, p: int,
-                 alternating: bool = False, interpret: bool = True):
+                 alternating: bool, interpret: bool):
     """Backup kernel over a G-slot arena.  All per-worker inputs carry a
     leading [G] axis ([G, p, D] paths, [G, p] scalars); `active` is a [G]
     mask.  Returns updated (edge_N, edge_W, edge_VL, node_N, node_O) in
@@ -200,20 +200,3 @@ def backup_arena(cfg: TreeConfig, arena, active, pn, pa, depths, leaves,
         cm.unpack_nodes_arena(nn2, X),
         cm.unpack_nodes_arena(no2, X),
     )
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "p", "alternating", "interpret"))
-def backup(cfg: TreeConfig, tree, pn, pa, depths, leaves, expand_action,
-           sim_nodes, values_fx, p: int, alternating: bool = False,
-           interpret: bool = True):
-    """Single-tree backup: the G=1 case of the arena kernel.  Returns
-    updated (edge_N, edge_W, edge_VL, node_N, node_O) in logical shapes."""
-    arena = jax.tree.map(lambda a: a[None], tree)
-    en, ew, evl, nn, no = backup_arena(
-        cfg, arena, jnp.ones((1,), jnp.int32), pn[None], pa[None],
-        jnp.asarray(depths)[None], jnp.asarray(leaves)[None],
-        jnp.asarray(expand_action)[None], jnp.asarray(sim_nodes)[None],
-        jnp.asarray(values_fx)[None], p=p, alternating=alternating,
-        interpret=interpret)
-    return en[0], ew[0], evl[0], nn[0], no[0]

@@ -26,7 +26,8 @@ aliased buffers pass through untouched), which keeps parked trees
 bit-frozen.  Single-tree selection is the G=1 case.
 
 The kernel is written for the TPU backend (2-D iotas, row-granular RMW,
-power-of-two edge blocks) and validated in interpret mode on CPU.
+power-of-two edge blocks, masked-sum lane extraction); the same kernel
+runs in the Pallas interpreter on the CPU backend (kernels/ops.py).
 """
 
 from __future__ import annotations
@@ -148,13 +149,11 @@ def _select_kernel(
 
             # memoization buffer write (paper §IV-E)
             d_lane = jax.lax.broadcasted_iota(i32, (1, D), 1)
-            pn_row = pl.load(pn_ref, (pl.dslice(j, 1), slice(None)))
-            pa_row = pl.load(pa_ref, (pl.dslice(j, 1), slice(None)))
+            pn_row = cm.load_row(pn_ref, j)
+            pa_row = cm.load_row(pa_ref, j)
             sel_d = active & (d_lane == d)
-            pl.store(pn_ref, (pl.dslice(j, 1), slice(None)),
-                     jnp.where(sel_d, node, pn_row))
-            pl.store(pa_ref, (pl.dslice(j, 1), slice(None)),
-                     jnp.where(sel_d, g_ - off, pa_row))
+            cm.store_row(pn_ref, j, jnp.where(sel_d, node, pn_row))
+            cm.store_row(pa_ref, j, jnp.where(sel_d, g_ - off, pa_row))
 
             nxt = cm.extract_lane(child_m, g_)
             node = jnp.where(active, nxt, node)
@@ -163,13 +162,9 @@ def _select_kernel(
             return node, depth
 
         node, depth = jax.lax.fori_loop(0, D, level, (root, i32(0)))
-        dep_row = pl.load(depth_ref, (slice(None), slice(None)))
-        leaf_row = pl.load(leaf_ref, (slice(None), slice(None)))
         sel_j = jax.lax.broadcasted_iota(i32, (1, p), 1) == j
-        pl.store(depth_ref, (slice(None), slice(None)),
-                 jnp.where(sel_j, depth, dep_row))
-        pl.store(leaf_ref, (slice(None), slice(None)),
-                 jnp.where(sel_j, node, leaf_row))
+        depth_ref[...] = jnp.where(sel_j, depth, depth_ref[...])
+        leaf_ref[...] = jnp.where(sel_j, node, leaf_ref[...])
         return 0
 
     # inactive slot -> no-op program: the pass-through copies above leave
@@ -180,8 +175,7 @@ def _select_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "p", "interpret"))
-def select_arena(cfg: TreeConfig, arena, active, p: int,
-                 interpret: bool = True):
+def select_arena(cfg: TreeConfig, arena, active, p: int, interpret: bool):
     """Selection kernel over a G-slot arena (one grid program per slot).
 
     `arena` is a UCTree whose leaves carry a leading [G] axis; `active` is
@@ -249,14 +243,3 @@ def select_arena(cfg: TreeConfig, arena, active, p: int,
         cm.unpack_nodes_arena(no2, X),
         pn, pa, dep[:, 0], leaf[:, 0],
     )
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "p", "interpret"))
-def select(cfg: TreeConfig, tree, p: int, interpret: bool = True):
-    """Single-tree selection: the G=1 case of the arena kernel.  Returns
-    (edge_VL', node_O', path_nodes, path_actions, depths, leaves) with
-    logical (unpacked) shapes."""
-    arena = jax.tree.map(lambda a: a[None], tree)
-    evl, no, pn, pa, dep, leaf = select_arena(
-        cfg, arena, jnp.ones((1,), jnp.int32), p, interpret=interpret)
-    return evl[0], no[0], pn[0], pa[0], dep[0], leaf[0]

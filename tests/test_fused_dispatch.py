@@ -10,9 +10,8 @@ pieces those legs rest on:
     XLA's simplifier cannot rewrite it (no division by a non-power-of-2
     constant, no FMA-contractable multiply-then-subtract);
   * the capability probes gate the fused path exactly;
-  * the fused program lowers as ONE compiled XLA program — including,
-    on the pallas leg, with the kernels' INTERPRET flag off (the
-    deployment configuration), compile-only so no TPU is needed.
+  * the fused program lowers as ONE compiled XLA program (the pallas
+    leg compiled for a TPU v5e lives in test_tpu_compile).
 """
 
 import numpy as np
@@ -129,22 +128,3 @@ def test_fused_program_lowers_single_program_faithful():
     text = lowered.as_text()
     assert "while" in text           # the fused superstep loop
     lowered.compile()                # compiles end-to-end on this host
-
-
-def test_fused_program_lowers_with_interpret_off_pallas():
-    """Compile-only deployment check: the pallas leg must still trace
-    and lower with kernels.ops.INTERPRET=False (real kernel lowering,
-    not the interpreter).  Skips where this backend cannot lower Pallas
-    kernels at all (CPU-only jaxlib builds)."""
-    from repro.kernels import ops as kops
-
-    old = kops.INTERPRET
-    kops.INTERPRET = False
-    try:
-        # fresh cfg -> fresh cache key -> really re-traces with the flag off
-        lowered = _lower("pallas", TreeConfig(X=80, F=4, D=6))
-    except Exception as e:  # noqa: BLE001 — backend-dependent lowering gap
-        pytest.skip(f"pallas kernels do not lower on this backend: {e}")
-    finally:
-        kops.INTERPRET = old
-    assert "while" in lowered.as_text()

@@ -1,11 +1,10 @@
 """Per-kernel sweeps: Pallas uct_select / uct_backup vs the pure-jnp oracle
 (kernels/ref.py), bit-exact, across fanouts / depths / worker counts /
-scoring variants.  Kernels run in interpret mode (CPU container; TPU is
-the compile target).
+scoring variants.  On the CPU backend kernels/ops.py runs the kernels in
+the Pallas interpreter; test_tpu_compile compiles them for a TPU v5e.
 """
 
-import dataclasses
-
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -14,6 +13,15 @@ from repro.core import TreeConfig, init_tree, intree, fixedpoint as fx
 from repro.core.tree import NULL
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+
+
+def _arena(tree):
+    """A single tree as the G=1 arena the kernels run on."""
+    return jax.tree.map(lambda a: jnp.asarray(a)[None], tree)
+
+
+def _slot0(tree):
+    return jax.tree.map(lambda a: a[0], tree)
 
 
 def grow_tree(cfg, supersteps=3, p=6, seed=0):
@@ -48,7 +56,8 @@ TREE_SWEEP = [
 def test_select_kernel_matches_ref(cfg, p):
     tree = grow_tree(cfg, supersteps=2, p=4)
     t_ref, sel_ref = kref.select_ref(cfg, tree, p)
-    t_k, sel_k = kops.select_batch(cfg, tree, p)
+    t_k, sel_k = kops.select_arena(cfg, _arena(tree), jnp.ones(1), p)
+    t_k, sel_k = _slot0(t_k), _slot0(sel_k)
     np.testing.assert_array_equal(np.asarray(t_ref.edge_VL),
                                   np.asarray(t_k.edge_VL))
     np.testing.assert_array_equal(np.asarray(t_ref.node_O),
@@ -74,7 +83,9 @@ def test_backup_kernel_matches_ref(cfg, alternating):
         fx.encode(rng.uniform(-1, 1, p).astype(np.float32))))
 
     t_ref = kref.backup_ref(cfg, tree, sel, sim_nodes, vals, alternating)
-    t_k = kops.backup_batch(cfg, tree, sel, sim_nodes, vals, alternating)
+    t_k = _slot0(kops.backup_arena(
+        cfg, _arena(tree), jnp.ones(1), _arena(sel), sim_nodes[None],
+        vals[None], alternating))
     for f in ("edge_N", "edge_W", "edge_VL", "node_N", "node_O"):
         np.testing.assert_array_equal(
             np.asarray(getattr(t_ref, f)), np.asarray(getattr(t_k, f)),

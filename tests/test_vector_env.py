@@ -140,6 +140,25 @@ def test_pool_fallback_matches_scalar():
     np.testing.assert_array_equal(na, env.num_actions_batch(ns2))
 
 
+class _PlatformProbeEnv:
+    """Scalar env whose action count reports the JAX platform its worker
+    process runs on: 1 when pinned to the CPU backend, 0 otherwise."""
+
+    def num_actions(self, state):
+        import jax
+        return int(jax.config.jax_platforms == "cpu"
+                   and jax.default_backend() == "cpu")
+
+
+def test_pool_workers_are_pinned_to_cpu():
+    """Expansion workers never reach for the parent's accelerator: the
+    pool initializer pins their JAX to the CPU backend."""
+    with PoolVectorEnv(_PlatformProbeEnv(), workers=2) as pv:
+        na = pv.num_actions_batch(np.zeros((4, 1)))
+        assert pv._pool is not None          # the batch went to workers
+    np.testing.assert_array_equal(na, np.ones(4, np.int64))
+
+
 def test_pool_fused_step_matches_two_call_form():
     """step_and_count_batch (ONE pooled round-trip) is bit-identical to
     step_batch followed by num_actions_batch."""
