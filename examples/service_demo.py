@@ -33,8 +33,10 @@ arena is freed; watch the pool summary) and resurrect on demand.
 slots are split into --gangs gangs and the superstep is double-buffered
 — gang A's host half (expansion + simulation IPC) runs while gang B's
 device in-tree phases (select -> insert) are already dispatched through
-JAX's async queue.  Results are bit-identical to lock-step; the summary
-prints the host-wait / device-wait / overlapped pipeline split.
+JAX's async queue.  Results are bit-identical to lock-step; with
+--trace-out the summary prints how long the main thread blocked on the
+env workers and on the device (the overlap-wait-env /
+overlap-wait-device spans).
 
 --frontend keeps the pre-handle ServiceFrontend adapter path.
 
@@ -144,20 +146,18 @@ def run_client(args):
               f"[{state}, idle={ps['idle_ticks']}]")
     s = client.stats
     if args.overlap:
-        # per-pool pipeline split: host wait (expansion/sim IPC) vs
-        # device wait (staged in-tree readback) vs overlapped wall time
-        wall = host = dev = 0.0
-        for pool in client.core.pools.values():
-            wall += pool._ov_wall
-            host += pool._ov_wait_host
-            dev += pool._ov_wait_dev
-        hid = max(wall - host - dev, 0.0)
+        # the pipeline's two blocking waits, from their trace spans
+        waits = ""
+        if client.tracer is not None:
+            sec = {"overlap-wait-env": 0.0, "overlap-wait-device": 0.0}
+            for e in client.trace_export()["traceEvents"]:
+                if e.get("ph") == "X" and e["name"] in sec:
+                    sec[e["name"]] += e["dur"] * 1e-6
+            waits = (f"; blocked on env workers "
+                     f"{sec['overlap-wait-env']:.3f}s, on the device "
+                     f"{sec['overlap-wait-device']:.3f}s")
         print(f"\noverlap pipeline ({args.gangs} gangs): "
-              f"{t_serve:.3f}s serving wall; per-tick split "
-              f"host-wait {host:.3f}s / device-wait {dev:.3f}s / "
-              f"overlapped {hid:.3f}s "
-              f"({100.0 * hid / max(wall, 1e-9):.0f}% of pipeline time "
-              f"hidden behind the other gang)")
+              f"{t_serve:.3f}s serving wall{waits}")
     else:
         print(f"\nserving wall time {t_serve:.3f}s "
               f"(re-run with --overlap to double-buffer gangs)")

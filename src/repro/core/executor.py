@@ -87,11 +87,13 @@ class InTreeExecutor(Protocol):
     def backup(self, active, sel, sim_nodes, values_fx, alternating: bool,
                dropped=None) -> None: ...
     # OPTIONAL fused fast path (device executors only — the reference
-    # executor keeps the phase-by-phase oracle): run up to K supersteps
-    # in one compiled program; see repro.core.fused.  Absence of the
-    # attribute means "host path only" (probe with hasattr).
-    def run_supersteps(self, active, p: int, K: int, env, sim, states,
-                       budget_left, alternating: bool): ...
+    # executor keeps the phase-by-phase oracle): queue up to K supersteps
+    # as one compiled program, then read it back; see repro.core.fused.
+    # Absence of the attribute means "host path only" (probe with
+    # hasattr).
+    def run_supersteps_submit(self, active, p: int, K: int, env, sim,
+                              states, budget_left, alternating: bool): ...
+    def run_supersteps_collect(self, pend): ...
     def sel_to_host(self, sel) -> dict: ...
     def best_actions(self) -> np.ndarray: ...
     def sizes(self) -> np.ndarray: ...
@@ -278,22 +280,11 @@ class JaxExecutor:
         # fences per-phase via block() when tracing.
 
     # -- fused multi-superstep dispatch --------------------------------
-    def run_supersteps(self, active, p: int, K: int, env, sim, states,
-                       budget_left, alternating: bool):
-        """Up to K fused supersteps in one compiled program (see
-        repro.core.fused).  Mutates self.trees; returns FusedDispatch."""
-        from repro.core import fused
-
-        self.trees, disp = fused.run_supersteps(
-            self.cfg, self._fused_variant, self.trees, np.asarray(active),
-            p, K, env, sim, states, budget_left, alternating)
-        return disp
-
     def run_supersteps_submit(self, active, p: int, K: int, env, sim,
                               states, budget_left, alternating: bool):
-        """Non-blocking half of run_supersteps: queue the fused program
-        and return a PendingDispatch of device outputs WITHOUT any host
-        read — the overlap mode's staged fused dispatch."""
+        """Queue up to K fused supersteps in one compiled program (see
+        repro.core.fused) and return a PendingDispatch of device outputs
+        WITHOUT any host read.  Mutates self.trees."""
         from repro.core import fused
 
         self.trees, pend = fused.submit_supersteps(
@@ -303,7 +294,7 @@ class JaxExecutor:
 
     def run_supersteps_collect(self, pend):
         """Blocking half: fetch the escape scalars / host views of a
-        staged dispatch.  run_supersteps == collect(submit(...))."""
+        staged dispatch as a FusedDispatch."""
         from repro.core import fused
 
         return fused.collect_supersteps(pend)
@@ -331,7 +322,8 @@ class JaxExecutor:
         self.trees = arena_set_slot(self.trees, g, to_jax(UCTree(**arrays)))
 
     def block(self):
-        jax.block_until_ready(self.trees.size)
+        """Wait for every queued write to the arena (tracing fences)."""
+        jax.block_until_ready(self.trees)
 
     def release(self):
         """Drop the arena's device arrays (cold-pool retirement).  The

@@ -88,6 +88,14 @@ class FusedDispatch:
     sel_host: Optional[dict]    # its host transfer
     new_nodes: Optional[np.ndarray]  # [Ge, p, Fp] (escape=="expand")
 
+    @property
+    def nbytes(self) -> int:
+        """Bytes read back from the device to build this result."""
+        arrays = [self.size_pre, self.sizes, self.states]
+        if self.new_nodes is not None:
+            arrays += [self.new_nodes, *self.sel_host.values()]
+        return sum(np.asarray(a).nbytes for a in arrays)
+
 
 def _zero_sel(Ge: int, p: int, D: int) -> intree.SelectionResult:
     z = jnp.zeros((Ge, p), jnp.int32)
@@ -209,8 +217,8 @@ class PendingDispatch:
 
     arena_size: Any      # [Ge] device sizes after the dispatch
     states_out: Any      # [Ge, X, *S] device ST buffer
-    n: Any               # scalar: complete supersteps executed
-    esc: Any             # scalar: escape code
+    n: Any               # scalar: complete supersteps (int once waited)
+    esc: Any             # scalar: escape code (int once waited)
     size_pre: Any        # [Ge] size before the most recent insert
     sel: Any             # device SelectionResult
     new_nodes: Any       # [Ge, p, Fp] device id block
@@ -220,9 +228,14 @@ def submit_supersteps(cfg: TreeConfig, variant: str, trees: UCTree,
                       active, p: int, K: int, env, sim, states,
                       budget_left, alternating: bool):
     """Queue up to K fused supersteps WITHOUT any host read.  Returns
-    (new_trees, PendingDispatch) — the overlap mode stages one gang's
-    dispatch here while another gang's host half runs, then redeems it
-    with collect_supersteps."""
+    (new_trees, PendingDispatch), which wait_supersteps blocks on and
+    collect_supersteps reads back.  ``states`` is the [Ge, X, *S] ST
+    image of the dispatched rows (uploaded once; new-node states come
+    back in FusedDispatch.states — node ids are allocated contiguously,
+    so the rows [size-at-dispatch-start, size_pre) are exactly the
+    device-resolved expansions the host tables are missing).  The
+    overlap mode stages one gang's dispatch here while another gang's
+    host half runs."""
     arena, states_out, n, esc, size_pre, sel, new_nodes = _fused_program(
         cfg, variant, p, K, env, sim, bool(alternating),
         trees, jnp.asarray(states), jnp.asarray(active, bool),
@@ -230,6 +243,13 @@ def submit_supersteps(cfg: TreeConfig, variant: str, trees: UCTree,
     return arena, PendingDispatch(
         arena_size=arena.size, states_out=states_out, n=n, esc=esc,
         size_pre=size_pre, sel=sel, new_nodes=new_nodes)
+
+
+def wait_supersteps(pend: PendingDispatch) -> None:
+    """Block until a staged fused program has run: read its two escape
+    scalars to the host, in place (collect_supersteps then reads the
+    rest, with the device already done)."""
+    pend.n, pend.esc = int(pend.n), int(pend.esc)
 
 
 def collect_supersteps(pend: PendingDispatch) -> FusedDispatch:
@@ -251,21 +271,3 @@ def collect_supersteps(pend: PendingDispatch) -> FusedDispatch:
         disp.sel_host = _sel_to_host(pend.sel)
         disp.new_nodes = np.asarray(jax.device_get(pend.new_nodes))
     return disp
-
-
-def run_supersteps(cfg: TreeConfig, variant: str, trees: UCTree,
-                   active, p: int, K: int, env, sim, states,
-                   budget_left, alternating: bool):
-    """Run up to K fused supersteps.  Returns (new_trees, FusedDispatch).
-
-    ``states`` is the [Ge, X, *S] host ST image for the dispatched rows
-    (uploaded once; new-node states come back in FusedDispatch.states —
-    node ids are allocated contiguously, so the rows
-    [size-at-dispatch-start, size_pre) are exactly the device-resolved
-    expansions the host tables are missing).  Exactly
-    collect_supersteps(submit_supersteps(...)) — the blocking wrapper
-    over the overlap mode's split."""
-    arena, pend = submit_supersteps(
-        cfg, variant, trees, active, p, K, env, sim, states,
-        budget_left, alternating)
-    return arena, collect_supersteps(pend)

@@ -36,7 +36,7 @@ the sharded executor therefore keeps D device-resident sub-arenas — one
 per device — behind the session API the pool already speaks.
 
 The fused K-superstep path stays per-shard by construction: the pool
-dispatches each child's `run_supersteps` separately (each shard runs to
+dispatches each child's fused program separately (each shard runs to
 its own commit/expansion escape on its own device) — see
 ArenaPool.fused_dispatch.
 """

@@ -186,6 +186,7 @@ def backup_arena(cfg: TreeConfig, arena, active, pn, pa, depths, leaves,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         input_output_aliases={8: 0, 9: 1, 10: 2, 11: 3, 12: 4},
+        name="backup_arena",   # the op name traces and readers match
         interpret=interpret,
     )(
         meta, pn, pa, depths.reshape(G, 1, p), leaves.reshape(G, 1, p),

@@ -235,6 +235,7 @@ def select_arena(cfg: TreeConfig, arena, active, p: int, interpret: bool):
         grid_spec=grid_spec,
         out_shape=out_shapes,
         input_output_aliases={10: 0, 11: 1},
+        name="select_arena",   # the op name traces and readers match
         interpret=interpret,
     )(meta, child_p, en_p, ew_p, ep_p, nn_p, ne_p, na_p, tm_p, lg_p,
       evl_p, no_p)

@@ -4,8 +4,12 @@ The numeric half of the observability layer (obs.trace is the temporal
 half): every serving layer registers its telemetry here — queue depth
 and smoothed load per bucket, fused-batch sizes, admission waits,
 eviction / retirement / expiry counts, expansion batch calls, compaction
-decisions — and ``render()`` emits one snapshot in Prometheus exposition
-format (the text format every scrape pipeline ingests):
+decisions, committed moves (``service_moves_committed_total``) and the
+bytes that cross between host and device on the fused path
+(``service_host_transfer_bytes_total{site,dir}``: site upload, readback,
+snapshot or write; dir h2d or d2h) — and ``render()`` emits one
+snapshot in Prometheus exposition format (the text format every scrape
+pipeline ingests):
 
     # HELP service_queue_depth requests queued, not yet admitted
     # TYPE service_queue_depth gauge
@@ -19,8 +23,7 @@ for per-superstep call sites.
 
 NULL_REGISTRY is the disabled path: the same surface returning shared
 no-op metric objects, `enabled` False, `render()` empty.  Layers default
-to it; the `service_obs_overhead` BENCH row pins the resulting
-disabled-path cost at well under the 2% CI gate.
+to it, so a disabled metric costs one no-op method call per bump.
 """
 
 from __future__ import annotations

@@ -3,9 +3,9 @@
 The paper's 35x in-tree and 3x system numbers rest on Fig. 8-style phase
 breakdowns: knowing, per superstep, where the time went on each side of
 the CPU/accelerator boundary.  This module is the measurement substrate
-for the serving stack — zero dependencies (stdlib only), cheap enough to
-stay wired into every layer, and exportable to the trace viewers people
-actually use:
+for the serving stack — importable without JAX (stdlib only), cheap
+enough to stay wired into every layer, and exportable to the trace
+viewers people actually use:
 
   Tracer      records four event kinds into a fixed-capacity ring
               (drop-oldest, no locks — a single writer index is the whole
@@ -16,27 +16,54 @@ actually use:
                   manager; per-track LIFO nesting is enforced, so a
                   malformed instrumentation site fails loudly instead of
                   exporting garbage;
-                * instants         — point events (admit / move-commit /
-                  cancel / retire);
+                * instants         — point events (submit / cancel /
+                  evict / retire);
                 * async spans      — async_begin()/async_end() pairs keyed
                   by (cat, name, id): request lifecycles that span many
                   ticks and interleave arbitrarily;
                 * track metadata   — track() names a timeline (scheduler,
                   one per arena pool) and returns its tid.
 
+              Every complete span is also a profiler annotation: begin()
+              enters a ``jax.profiler.TraceAnnotation`` of the span's name
+              and end() exits it, so under ``jax.profiler.trace`` the
+              profiler's host plane carries each span on the same clock
+              as the device ops.  Each open Span holds its own annotation
+              object, so spans on different tracks may end in any order.
+              JAX is imported when a live Tracer is built, never by this
+              module; without JAX the spans go to the ring alone.
+
   export()    Chrome-trace / Perfetto JSON ({"traceEvents": [...]}):
               load the file at ui.perfetto.dev or chrome://tracing.
-              Timestamps are microseconds relative to Tracer creation.
+              Timestamps are microseconds relative to Tracer creation;
+              ``otherData["dropped"]`` counts the events the ring lost, so
+              a reader can refuse a stretch that is incomplete.
 
   NULL_TRACER the disabled path: same surface, every method a no-op,
               `enabled` False so call sites can gate explicit
               block_until_ready fences on tracing being live.  Layers
-              default to it, which is what keeps the disabled-path
-              overhead at a handful of no-op calls per superstep
-              (measured by the `service_obs_overhead` BENCH row).
+              default to it, which keeps the disabled path at a handful
+              of no-op calls per superstep (the cost of tracing on, as
+              measured on a TPU v5e, is in the package docstring).
 
 The clock is injectable (``clock_ns``) so tests can pin nesting and
 ordering deterministically.
+
+Spans of the serving stack (service/pool.py), on each pool's track:
+
+  superstep > admit, select, expand, simulate,       classic phase path
+      backup, move-commit
+  fused-dispatch > admit, fused-upload, fused-run,    fused K-superstep
+      fused-readback, move-commit                     dispatch
+  move-commit > commit-snapshot, commit-reroot,       one committed move
+      commit-write
+  overlap-wait-env / overlap-wait-device              overlap mode's two
+                                                      blocking waits
+  compact-gather / compact-scatter                    compaction sessions
+
+A span that ends on a device transfer or write is fenced with
+``block_until_ready`` only while tracing, so its time is the transfer's;
+untraced runs get no fence.
 """
 
 from __future__ import annotations
@@ -52,11 +79,12 @@ class Span:
     """An open span: the token begin() hands out and end() consumes.
     Carries everything the eventual "X" record needs except duration."""
 
-    __slots__ = ("name", "cat", "tid", "ts", "args", "depth")
+    __slots__ = ("name", "cat", "tid", "ts", "args", "depth", "note")
 
-    def __init__(self, name, cat, tid, ts, args, depth):
+    def __init__(self, name, cat, tid, ts, args, depth, note=None):
         self.name, self.cat, self.tid = name, cat, tid
         self.ts, self.args, self.depth = ts, args, depth
+        self.note = note    # the span's open profiler annotation
 
 
 class _SpanCtx:
@@ -119,6 +147,11 @@ class Tracer:
             "ph": "M", "name": "process_name", "pid": pid, "tid": 0,
             "args": {"name": "search-service"},
         }]
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = None
+        self._annotation = TraceAnnotation
 
     # ---- clock / buffer ----
     def _now_us(self) -> float:
@@ -153,21 +186,30 @@ class Tracer:
     # ---- complete spans ----
     def begin(self, name: str, cat: str = "", tid: int = 0, **args) -> Span:
         stack = self._stacks.setdefault(tid, [])
-        tok = Span(name, cat, tid, self._now_us(), args, len(stack))
+        note = None
+        if self._annotation is not None:
+            note = self._annotation(name)
+            note.__enter__()
+        tok = Span(name, cat, tid, self._now_us(), args, len(stack), note)
         stack.append(tok)
         return tok
 
-    def end(self, tok: Span):
+    def end(self, tok: Span, **args):
+        """Close `tok`; `args` join the ones given at begin() (values
+        known only when the span's work is done)."""
         stack = self._stacks.get(tok.tid)
         assert stack and stack[-1] is tok, (
             f"span end out of order on track {tok.tid}: ending "
             f"{tok.name!r} but "
             f"{stack[-1].name if stack else '<empty>'!r} is open")
         stack.pop()
+        dur = self._now_us() - tok.ts
+        if tok.note is not None:
+            tok.note.__exit__(None, None, None)
+        tok.args.update(args)
         self._push({
             "ph": "X", "name": tok.name, "cat": tok.cat, "pid": self.pid,
-            "tid": tok.tid, "ts": tok.ts,
-            "dur": self._now_us() - tok.ts, "args": tok.args})
+            "tid": tok.tid, "ts": tok.ts, "dur": dur, "args": tok.args})
 
     def span(self, name: str, cat: str = "", tid: int = 0,
              **args) -> _SpanCtx:
@@ -222,7 +264,8 @@ class Tracer:
             if ev.get("args"):
                 ev["args"] = {k: _jsonable(v) for k, v in ev["args"].items()}
             events.append(ev)
-        out = {"traceEvents": events, "displayTimeUnit": "ms"}
+        out = {"traceEvents": events, "displayTimeUnit": "ms",
+               "otherData": {"dropped": self.dropped}}
         if path is not None:
             with open(path, "w") as f:
                 json.dump(out, f)
@@ -258,7 +301,7 @@ class NullTracer:
     def begin(self, name, cat="", tid=0, **args):
         return None
 
-    def end(self, tok):
+    def end(self, tok, **args):
         pass
 
     def span(self, name, cat="", tid=0, **args) -> _NullSpanCtx:

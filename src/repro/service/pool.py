@@ -121,10 +121,11 @@ import dataclasses
 import time
 from typing import Callable, Optional
 
+import jax
 import numpy as np
 
 from repro.core import fixedpoint as fx
-from repro.core import reroot
+from repro.core import fused, reroot
 from repro.core.executor import CompactionSession, make_intree_executor
 from repro.core.expand import ExpansionEngine
 from repro.core.mcts import Environment, SimulationBackend
@@ -427,6 +428,18 @@ class ArenaPool:
             "service_compaction_events_total", bucket=label, event="reuse")
         self._m_scatters = reg.counter(
             "service_compaction_events_total", bucket=label, event="scatter")
+        self._m_moves = reg.counter(
+            "service_moves_committed_total", "moves committed", bucket=label)
+        # bytes that cross between host and device, by call site: the
+        # fused dispatch's upload and readback, the commit's snapshot
+        # and write-back
+        self._m_bytes = {
+            site: reg.counter(
+                "service_host_transfer_bytes_total",
+                "bytes copied between host and device by call site",
+                bucket=label, site=site, dir=direction)
+            for site, direction in (("upload", "h2d"), ("readback", "d2h"),
+                                    ("snapshot", "d2h"), ("write", "h2d"))}
         # host-expansion engine: "loop" per-worker env.step, "vector" ONE
         # flattened step_batch over all slots' pending expansions, "pool"
         # the process-pool scalar fallback (core.expand) — bit-identical.
@@ -507,23 +520,6 @@ class ArenaPool:
         self._inflight: Optional[_InflightGang] = None
         self._inflight_fused: Optional[dict] = None
         self._gang_tids: dict = {}
-        # overlap busy-ratio bookkeeping: wall seconds of overlap ticks,
-        # and how much of them the main thread spent BLOCKED on the env
-        # workers (host side) / on device readbacks (device side)
-        self._ov_wall = 0.0
-        self._ov_wait_host = 0.0
-        self._ov_wait_dev = 0.0
-        if self.overlap:
-            self._m_busy_host = reg.gauge(
-                "service_overlap_busy_ratio",
-                "fraction of overlap-tick wall the main thread was not "
-                "blocked, by waiting side", bucket=label, side="host")
-            self._m_busy_dev = reg.gauge(
-                "service_overlap_busy_ratio", bucket=label, side="device")
-            self._m_ov_eff = reg.histogram(
-                "service_overlap_efficiency",
-                "per-tick percent of wall not spent blocked on env "
-                "workers or device readbacks", bucket=label)
         # fixed per-slot finalize width (vmapped finalize needs one shape)
         self.K = p * cfg.Fp if cfg.expand_all else p
 
@@ -614,20 +610,22 @@ class ArenaPool:
                 res.terminal = True
                 self._finish(res)
                 continue
-            self.exec.reset_slot(g, na)
-            self.sts[g].flush(s0)
+            wait = max(0, self._now() - max(req.submit_tick, 0))
+            with self.trace.span("admit", cat="request", tid=self._track,
+                                 uid=req.uid, slot=g,
+                                 shard=g // self.shard_G, wait=wait):
+                self.exec.reset_slot(g, na)   # builds the tree on device
+                self.sts[g].flush(s0)
+                if self.trace.enabled:
+                    self.exec.block()
             self.slots[g] = _Slot(req=req, res=res, root_state=s0,
                                   cfg=req.cfg if req.cfg is not None
                                   else self.cfg)
             self.stats.admitted += 1
-            wait = max(0, self._now() - max(req.submit_tick, 0))
             self.stats.wait_supersteps[wait] = (
                 self.stats.wait_supersteps.get(wait, 0) + 1)
             self._m_admitted.inc()
             self._m_wait.observe(wait)
-            self.trace.instant("admit", cat="request", tid=self._track,
-                               uid=req.uid, slot=g, shard=g // self.shard_G,
-                               wait=wait)
             active += 1
 
     def _active(self) -> np.ndarray:
@@ -864,10 +862,11 @@ class ArenaPool:
         From here the gang's env workers step concurrently with whatever
         the main thread does next (evaluate/finish of another gang)."""
         t0 = time.perf_counter()
-        sel = self.exec.sel_to_host(st.sel_dev)
-        new_nodes = self.exec.insert_host(st.new_nodes_dev)
+        with self.trace.span("overlap-wait-device", cat="phase",
+                             tid=self._gang_track(st.gang), gang=st.gang):
+            sel = self.exec.sel_to_host(st.sel_dev)
+            new_nodes = self.exec.insert_host(st.new_nodes_dev)
         t_dev = time.perf_counter() - t0
-        self._ov_wait_dev += t_dev
         pexp = self.expander.expand_submit(
             [(g, self.sts[g], {k: v[g] for k, v in sel.items()},
               new_nodes[g]) for g in st.act_idx],
@@ -885,10 +884,11 @@ class ArenaPool:
         the ordinary _PendingStep the caller evaluates and finishes."""
         inf, self._inflight = self._inflight, None
         t0 = time.perf_counter()
-        hx = self.expander.expand_collect(
-            inf.pexp, tid=self._gang_tids.get(inf.gang, self._track))
+        tid = self._gang_tids.get(inf.gang, self._track)
+        with self.trace.span("overlap-wait-env", cat="phase", tid=tid,
+                             gang=inf.gang):
+            hx = self.expander.expand_collect(inf.pexp, tid=tid)
         t_wait = time.perf_counter() - t0
-        self._ov_wait_host += t_wait
         self.stats.t_expand += inf.t_submit + t_wait
         sim_states = np.concatenate([hx[g].sim_states for g in inf.act_idx])
         return _PendingStep(
@@ -909,7 +909,6 @@ class ArenaPool:
             # gangs, or the same slots could select twice concurrently
             self.drain_overlap()
         self.stats.ticks += 1
-        t_tick0 = time.perf_counter()
         self._admit()
         self._m_queue.set(len(self.queue))
         active = self._active()
@@ -934,14 +933,6 @@ class ArenaPool:
             self._stage(nxt, active))
         pend = self._collect_inflight()
         self._inflight = promoted
-        wall = time.perf_counter() - t_tick0
-        self._ov_wall += wall
-        if self._ov_wall > 0:
-            self._m_busy_host.set(1.0 - self._ov_wait_host / self._ov_wall)
-            self._m_busy_dev.set(1.0 - self._ov_wait_dev / self._ov_wall)
-        self._m_ov_eff.observe(100.0 * max(
-            0.0, 1.0 - (self._ov_wait_host + self._ov_wait_dev)
-            / max(self._ov_wall, 1e-12)))
         return pend
 
     def drain_overlap(self) -> int:
@@ -1141,10 +1132,10 @@ class ArenaPool:
             return False
         shards = getattr(ex, "shards", None)
         if shards is not None:
-            fused_ok = all(hasattr(c, "run_supersteps")
+            fused_ok = all(hasattr(c, "run_supersteps_submit")
                            for c, _, _ in shards)
         else:
-            fused_ok = hasattr(ex, "run_supersteps")
+            fused_ok = hasattr(ex, "run_supersteps_submit")
         return (not self.cfg.expand_all
                 and fused_ok
                 and has_device_env(self.env)
@@ -1234,36 +1225,71 @@ class ArenaPool:
         the sharded path, where the caller's loop holds one span over
         all shards)."""
         t0 = time.perf_counter()
+        disp = self._fused_run(ex, ex_active, rows, act_idx, K)
+        return self._fused_finish_one(ex, ex_active, rows, act_idx, disp,
+                                      on_sub, tok, t0)
+
+    def _fused_run(self, ex, ex_active, rows, act_idx, K: int):
+        """Upload, run and read back one fused dispatch.  Its device
+        buffers go with this frame, before the move commits."""
         budget_left, states, start_size = self._fused_upload(
             ex, rows, act_idx)
-        disp = ex.run_supersteps(ex_active, self.p, K, self.env, self.sim,
-                                 states, budget_left,
-                                 self.alternating_signs)
-        return self._fused_finish_one(ex, ex_active, rows, act_idx, disp,
-                                      start_size, on_sub, tok, t0)
+        with self.trace.span("fused-run", cat="phase", tid=self._track):
+            pend = ex.run_supersteps_submit(
+                ex_active, self.p, K, self.env, self.sim, states,
+                budget_left, self.alternating_signs)
+            fused.wait_supersteps(pend)   # the host waits on the device
+        return self._fused_readback(ex, pend, rows, act_idx, start_size)
 
     def _fused_upload(self, ex, rows, act_idx):
         """Host half of a fused dispatch's inputs: per-row remaining move
         budgets + ONE upload of the dispatched rows' ST images; the
         buffer stays device-resident for the whole dispatch (fused
-        supersteps cost zero H2D copies)."""
-        Ge = ex.G
-        budget_left = np.zeros(Ge, np.int32)
-        states = np.zeros((Ge, self.cfg.X) + tuple(self.env.state_shape),
-                          self.env.state_dtype)
-        start_size = np.ones(Ge, np.int64)
-        for r, g in zip(rows, act_idx):
-            slot = self.slots[g]
-            budget_left[r] = slot.req.budget - slot.move_supersteps
-            states[r] = self.sts[g].data
-            start_size[r] = slot.prev_size
-        return budget_left, states, start_size
+        supersteps cost zero H2D copies).  Returns the two uploaded
+        arrays and the host's per-row start sizes."""
+        with self.trace.span("fused-upload", cat="phase", tid=self._track):
+            Ge = ex.G
+            budget_left = np.zeros(Ge, np.int32)
+            states = np.zeros(
+                (Ge, self.cfg.X) + tuple(self.env.state_shape),
+                self.env.state_dtype)
+            start_size = np.ones(Ge, np.int64)
+            for r, g in zip(rows, act_idx):
+                slot = self.slots[g]
+                budget_left[r] = slot.req.budget - slot.move_supersteps
+                states[r] = self.sts[g].data
+                start_size[r] = slot.prev_size
+            self._m_bytes["upload"].inc(states.nbytes + budget_left.nbytes)
+            budget_dev, states_dev = jax.device_put((budget_left, states))
+            if self.trace.enabled:
+                jax.block_until_ready(states_dev)
+        return budget_dev, states_dev, start_size
+
+    def _fused_readback(self, ex, pend, rows, act_idx, start_size):
+        """Read a finished fused dispatch back (its FusedDispatch) and
+        pull the device-resolved expansion states into the host tables:
+        node ids are allocated contiguously, so rows
+        [size-at-dispatch-start, end) are exactly the entries the host
+        is missing.  An expansion escape excludes the escaped
+        superstep's insert (the host expansion path writes those)."""
+        with self.trace.span("fused-readback", cat="phase",
+                             tid=self._track):
+            disp = ex.run_supersteps_collect(pend)
+            self._m_bytes["readback"].inc(disp.nbytes)
+            expand = disp.escape == "expand"
+            for r, g in zip(rows, act_idx):
+                end = int(disp.size_pre[r] if expand else disp.sizes[r])
+                lo = int(start_size[r])
+                if end > lo:
+                    self.sts[g].write(np.arange(lo, end),
+                                      disp.states[r, lo:end])
+        return disp
 
     def _fused_finish_one(self, ex, ex_active, rows, act_idx, disp,
-                          start_size, on_sub: bool, tok, t0: float) -> int:
-        """Accounting + escape handling for one collected fused dispatch
+                          on_sub: bool, tok, t0: float) -> int:
+        """Accounting + escape handling for one read-back fused dispatch
         (the post-device half of _fused_dispatch_one; the overlap path
-        reaches it through run_supersteps_submit/collect instead)."""
+        reaches it through _fused_collect_gang instead)."""
         A, p = len(act_idx), self.p
         n = disp.n
         t1 = time.perf_counter()
@@ -1280,17 +1306,6 @@ class ArenaPool:
             "service_fused_dispatches_total",
             "fused K-superstep device dispatches by escape reason",
             bucket=bucket_label(self.cfg), escape=disp.escape).inc()
-        # pull device-resolved expansion states back into the host
-        # tables: node ids are allocated contiguously, so rows
-        # [size-at-dispatch-start, end) are exactly the entries the host
-        # is missing.  An expansion escape excludes the escaped
-        # superstep's insert (the host expansion path writes those).
-        for r, g in zip(rows, act_idx):
-            end = int(disp.size_pre[r] if expand else disp.sizes[r])
-            lo = int(start_size[r])
-            if end > lo:
-                self.sts[g].write(np.arange(lo, end),
-                                  disp.states[r, lo:end])
         # accounting for the device-complete supersteps.  The LAST
         # complete superstep of a normal exit goes through _commit_moves
         # exactly like the K=1 path (so move commits / evictions /
@@ -1389,13 +1404,16 @@ class ArenaPool:
         path)."""
         ns = [0]
         for part in inf["parts"]:
-            t_c0 = time.perf_counter()
-            disp = part["child"].run_supersteps_collect(part["pend"])
-            self._ov_wait_dev += time.perf_counter() - t_c0
+            with self.trace.span("overlap-wait-device", cat="phase",
+                                 tid=self._track, gang=inf["gang"]):
+                fused.wait_supersteps(part["pend"])
+            disp = self._fused_readback(part["child"], part["pend"],
+                                        part["rows"], part["act_idx"],
+                                        part["start_size"])
             ns.append(self._fused_finish_one(
                 part["child"], part["c_active"], part["rows"],
-                part["act_idx"], disp, part["start_size"],
-                on_sub=False, tok=None, t0=part["t0"]))
+                part["act_idx"], disp, on_sub=False, tok=None,
+                t0=part["t0"]))
         return max(ns)
 
     def _fused_overlap_tick(self, K: int) -> int:
@@ -1405,7 +1423,6 @@ class ArenaPool:
         if self._inflight is not None:   # mode switch: K rose above 1
             self.drain_overlap()
         self.stats.ticks += 1
-        t_tick0 = time.perf_counter()
         tok = self.trace.begin("fused-dispatch", cat="phase",
                                tid=self._track, tick=self._now(), k=K,
                                overlap=True)
@@ -1432,11 +1449,6 @@ class ArenaPool:
         n = self._fused_collect_gang(inf)
         self._inflight_fused = staged
         self.trace.end(tok)
-        wall = time.perf_counter() - t_tick0
-        self._ov_wall += wall
-        if self._ov_wall > 0:
-            self._m_busy_host.set(1.0 - self._ov_wait_host / self._ov_wall)
-            self._m_busy_dev.set(1.0 - self._ov_wait_dev / self._ov_wall)
         return n
 
     # ---- move boundary: commit / advance / evict ----
@@ -1461,8 +1473,24 @@ class ArenaPool:
             self._advance(g, int(best[g]))
 
     def _advance(self, g: int, a: int):
-        slot, env = self.slots[g], self.env
-        snap = self._slot_snapshot(g)
+        slot = self.slots[g]
+        tok = self.trace.begin("move-commit", cat="request", tid=self._track,
+                               uid=slot.req.uid, move=slot.moves_done,
+                               action=a)
+        last = self._commit_move(g, slot, a)
+        self.trace.end(tok, last=last)
+        self._m_moves.inc()
+
+    def _commit_move(self, g: int, slot: _Slot, a: int) -> bool:
+        """Commit action `a` of slot g: emit the move, then finish the
+        request or re-root its tree for the next move.  Returns whether
+        it was the request's last move."""
+        env = self.env
+        with self.trace.span("commit-snapshot", cat="request",
+                             tid=self._track):
+            snap = self._slot_snapshot(g)
+            self._m_bytes["snapshot"].inc(
+                sum(v.nbytes for v in snap.values()))
         # every path below rewrites or frees this slot on the full arena,
         # so a resident sub-arena spanning it must end now (its final
         # state was just scattered by the snapshot sync)
@@ -1475,9 +1503,6 @@ class ArenaPool:
         slot.res.visit_counts.append(counts)
         slot.moves_done += 1
         last = bool(term) or slot.moves_done >= slot.req.moves
-        self.trace.instant("move-commit", cat="request", tid=self._track,
-                           uid=slot.req.uid, move=slot.moves_done - 1,
-                           action=a, last=last)
         if self.move_listener is not None:
             self.move_listener(MoveEvent(
                 uid=slot.req.uid, move_index=slot.moves_done - 1, action=a,
@@ -1488,20 +1513,32 @@ class ArenaPool:
                 slot.res.tree_snapshot = snap
             self._finish(slot.res)
             self.slots[g] = None
-            return
+            return True
         # long-lived request: next move on the same slot
         slot.root_state = new_state
         slot.move_supersteps = 0
         new_root = int(snap["child"][root, a])
-        if self.reuse_subtree and new_root != NULL:
-            arrays, old2new = reroot.reroot(self.cfg, snap, new_root)
-            self.exec.write_slot(g, arrays)
-            self.sts[g].compact(old2new)
-            slot.prev_size = int(arrays["size"])
-        else:  # paper-faithful full flush
-            self.exec.reset_slot(g, max(env.num_actions(new_state), 1))
-            self.sts[g].flush(new_state)
-            slot.prev_size = 1
+        reuse = self.reuse_subtree and new_root != NULL
+        with self.trace.span("commit-reroot", cat="request",
+                             tid=self._track, reuse=reuse):
+            if reuse:
+                arrays, old2new = reroot.reroot(self.cfg, snap, new_root)
+                self.sts[g].compact(old2new)
+            else:  # paper-faithful full flush
+                self.sts[g].flush(new_state)
+        with self.trace.span("commit-write", cat="request",
+                             tid=self._track):
+            if reuse:
+                self.exec.write_slot(g, arrays)
+                self._m_bytes["write"].inc(
+                    sum(np.asarray(v).nbytes for v in arrays.values()))
+                slot.prev_size = int(arrays["size"])
+            else:   # the fresh tree is built on the device
+                self.exec.reset_slot(g, max(env.num_actions(new_state), 1))
+                slot.prev_size = 1
+            if self.trace.enabled:
+                self.exec.block()
+        return False
 
     def _finish(self, res: SearchResult):
         res.done_at = time.perf_counter()

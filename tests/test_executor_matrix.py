@@ -594,8 +594,9 @@ def test_overlap_fused_sharded_composes():
 
 def test_overlap_trace_exposes_gang_tracks():
     """The obs satellite: an overlap run with tracing on emits per-gang
-    timeline tracks and the busy-ratio/efficiency overlap metrics, and
-    tracing still never changes WHAT is computed."""
+    timeline tracks and spans around the pipeline's two blocking waits
+    (env workers, device readback), and tracing still never changes
+    WHAT is computed."""
     from repro.obs import MetricsRegistry, Tracer
 
     cl = SearchClient(ENV, BanditValueBackend(), G=SHARD_G, p=P,
@@ -620,8 +621,8 @@ def test_overlap_trace_exposes_gang_tracks():
     # the async split renames the expansion phase into its two halves
     assert {"superstep", "select", "expand-submit", "expand-collect",
             "simulate"} <= names
-    assert "service_overlap_busy_ratio" in metrics
-    assert "service_overlap_efficiency" in metrics
+    assert {"overlap-wait-env", "overlap-wait-device"} <= names
+    assert "service_supersteps_total" in metrics
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,13 @@ Claim groups:
     weighted-queue-depth gang tick with compaction enabled exports
     valid Chrome-trace JSON covering all six superstep phases and the
     full request lifecycle (submit -> result and submit -> evict), and
-    client.metrics() renders the scheduler/pool telemetry.
+    client.metrics() renders the scheduler/pool telemetry;
+  * fused path — a K=4 fused run splits each fused-dispatch into
+    fused-upload / fused-run / fused-readback (plus admit and
+    move-commit) and each move-commit into commit-snapshot /
+    commit-reroot / commit-write, the children cover their parents,
+    the transfer counters count the bytes the shapes give, and the
+    spans reach the JAX profiler's host plane.
 """
 
 import json
@@ -139,6 +145,37 @@ def test_tracer_rejects_bad_capacity():
         Tracer(capacity=0)
 
 
+def test_end_args_join_begin_args():
+    tr = Tracer(clock_ns=_fake_clock())
+    tok = tr.begin("move-commit", uid=3)
+    tr.end(tok, last=True)
+    assert tr.events()[0]["args"] == {"uid": 3, "last": True}
+
+
+def test_export_reports_dropped_events():
+    tr = Tracer(capacity=2, clock_ns=_fake_clock())
+    for i in range(5):
+        tr.instant(f"i{i}")
+    assert tr.export()["otherData"] == {"dropped": 3}
+
+
+def test_spans_on_two_tracks_end_in_any_order_under_the_profiler(tmp_path):
+    """Each open span holds its own profiler annotation, so spans on
+    different tracks may close out of order while a profile records."""
+    import jax
+
+    tr = Tracer()
+    t0, t1 = tr.track("pool:a"), tr.track("pool:a:gang1")
+    with jax.profiler.trace(str(tmp_path)):
+        a = tr.begin("fused-dispatch", tid=t0)
+        b = tr.begin("overlap-wait-device", tid=t1)
+        assert a.note is not None and a.note is not b.note
+        tr.end(a)
+        tr.end(b)
+    assert [e["name"] for e in tr.events()] == ["fused-dispatch",
+                                                "overlap-wait-device"]
+
+
 # ---------------------------------------------------------------------------
 # metrics
 # ---------------------------------------------------------------------------
@@ -252,3 +289,123 @@ def test_three_bucket_run_exports_phases_and_lifecycle():
     snap = cl.registry.snapshot()
     assert any(k.startswith("service_queue_depth") for k in snap)
     cl.close()
+
+
+# ---------------------------------------------------------------------------
+# fused path: the dispatch's and the commit's inner spans, transfer bytes
+# ---------------------------------------------------------------------------
+
+FUSED_CFG = TreeConfig(X=256, F=4, D=5)
+FUSED_G = 2
+
+
+def _fused_client(**obs):
+    return SearchClient(
+        BanditTreeEnv(fanout=4, terminal_depth=8), BanditValueBackend(),
+        G=FUSED_G, p=4, executor="faithful", default_cfg=FUSED_CFG,
+        supersteps_per_dispatch=4, **obs)
+
+
+def _serve(cl, n=3):
+    handles = [cl.submit(SearchRequest(uid=i, seed=i, budget=4, moves=3))
+               for i in range(n)]
+    return {h.uid: h.result() for h in handles}
+
+
+def _direct_children(spans, parent):
+    """Spans on the parent's track inside it and inside no other one."""
+    inside = [c for c in spans if c is not parent
+              and c["tid"] == parent["tid"]
+              and parent["ts"] <= c["ts"]
+              and c["ts"] + c["dur"] <= parent["ts"] + parent["dur"]]
+    return [c for c in inside if not any(
+        o is not c and o["ts"] <= c["ts"]
+        and c["ts"] + c["dur"] <= o["ts"] + o["dur"] for o in inside)]
+
+
+def test_fused_run_splits_dispatch_and_commit_into_spans():
+    import jax
+
+    from repro.core.tree import init_arena
+
+    cl = _fused_client(trace=Tracer(), metrics=MetricsRegistry())
+    done = _serve(cl)
+    trace = cl.trace_export()
+    reg, stats = cl.registry, cl.stats
+    cl.close()
+    plain = _fused_client()
+    assert {u: (r.actions, [list(v) for v in r.visit_counts])
+            for u, r in _serve(plain).items()} == \
+        {u: (r.actions, [list(v) for v in r.visit_counts])
+         for u, r in done.items()}
+    plain.close()
+
+    spans = [e for e in trace["traceEvents"] if e.get("ph") == "X"]
+    kids = {"fused-dispatch": {"admit", "fused-upload", "fused-run",
+                               "fused-readback", "move-commit"},
+            "move-commit": {"commit-snapshot", "commit-reroot",
+                            "commit-write"}}
+    for parent_name, allowed in kids.items():
+        parents = [e for e in spans if e["name"] == parent_name]
+        assert parents, parent_name
+        covered = 0.0
+        seen = set()
+        for parent in parents:
+            children = _direct_children(spans, parent)
+            assert {c["name"] for c in children} <= allowed, parent_name
+            seen |= {c["name"] for c in children}
+            covered += sum(c["dur"] for c in children)
+        assert seen == allowed, (parent_name, seen)
+        assert covered >= 0.9 * sum(p["dur"] for p in parents), parent_name
+    for parent in (e for e in spans if e["name"] == "fused-dispatch"):
+        names = [c["name"] for c in sorted(_direct_children(spans, parent),
+                                           key=lambda c: c["ts"])]
+        run = [n for n in names if n.startswith("fused-")]
+        assert run == ["fused-upload", "fused-run", "fused-readback"]
+    last = [e["args"]["last"] for e in spans if e["name"] == "move-commit"]
+    assert last.count(True) == len(done)
+
+    # transfer bytes from the shapes: per dispatch the state image (and
+    # budgets) up, the states and two size rows down; per move one
+    # slot's UCT down, per re-rooted move one slot's UCT up
+    label = f"X{FUSED_CFG.X}_D{FUSED_CFG.D}_Fp{FUSED_CFG.Fp}"
+    moved = {site: reg.get("service_host_transfer_bytes_total",
+                           bucket=label, site=site, dir=d).value
+             for site, d in (("upload", "h2d"), ("readback", "d2h"),
+                             ("snapshot", "d2h"), ("write", "h2d"))}
+    image = FUSED_G * FUSED_CFG.X * 8 * 4      # state (8,) float32
+    slot = sum(a.nbytes
+               for a in jax.tree.leaves(init_arena(FUSED_CFG, 1)))
+    dispatches = stats.fused_dispatches
+    moves = reg.get("service_moves_committed_total", bucket=label).value
+    rerooted = sum(e["args"]["reuse"] for e in spans
+                   if e["name"] == "commit-reroot")
+    assert stats.fused_escape_expand == 0
+    assert moves == sum(len(r.actions) for r in done.values()) == 9
+    assert moved == {"upload": dispatches * (image + FUSED_G * 4),
+                     "readback": dispatches * (image + 2 * FUSED_G * 4),
+                     "snapshot": moves * slot,
+                     "write": rerooted * slot}
+    assert rerooted == moves - len(done)
+
+
+def test_spans_reach_the_profilers_host_plane(tmp_path):
+    """Under jax.profiler.trace the client's spans are host events of
+    the profile, on the device's clock, as a traced benchmark run
+    records them."""
+    import jax
+
+    from perfbench import traces
+
+    cl = _fused_client(trace=Tracer())
+    with jax.profiler.trace(str(tmp_path),
+                            profiler_options=traces.profile_options()):
+        _serve(cl, n=2)
+    cl.close()
+    (path,) = list(tmp_path.rglob("*.xplane.pb"))
+    host = [ev.name for plane in jax.profiler.ProfileData.from_file(
+        str(path)).planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events]
+    for name in ("fused-dispatch", "fused-upload", "fused-run",
+                 "fused-readback", "move-commit", "commit-snapshot"):
+        assert name in host, name

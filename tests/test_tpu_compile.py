@@ -14,6 +14,7 @@ worker imports this file.
 """
 
 import os
+import re
 
 import pytest
 
@@ -110,12 +111,13 @@ def test_uct_kernels_compile_gomoku_paper_size(one_chip, kernel):
     assert "tpu_custom_call" in text
 
 
-def test_fused_pallas_program_compiles_pong_paper_size(one_chip):
+@pytest.fixture(scope="module")
+def fused_pong_text(one_chip):
     """The fused K=4 superstep program on the pallas variant, G=4, at Pong
-    size.  Its kernels go through kernels.ops, which picks compiled
-    kernels because the program is lowered for the TPU the arguments
-    are placed on — the test steers the platform only through that
-    placement."""
+    size, compiled once for the module.  Its kernels go through
+    kernels.ops, which picks compiled kernels because the program is
+    lowered for the TPU the arguments are placed on — the test steers the
+    platform only through that placement."""
     cfg, G, K = pong.TREE, 4, 4
     env = BanditTreeEnv(fanout=cfg.F, terminal_depth=cfg.D + 1)
     sim = BanditValueBackend()
@@ -126,6 +128,26 @@ def test_fused_pallas_program_compiles_pong_paper_size(one_chip):
         _arena(cfg, G, one_chip), states,
         jax.ShapeDtypeStruct((G,), jnp.bool_, sharding=one_chip),
         _i32(one_chip, G))
-    text = _compiled_text(lowered)
-    assert "tpu_custom_call" in text
-    assert "while" in text
+    return _compiled_text(lowered)
+
+
+def test_fused_pallas_program_compiles_pong_paper_size(fused_pong_text):
+    assert "tpu_custom_call" in fused_pong_text
+    assert "while" in fused_pong_text
+
+
+def test_fused_program_kernel_names_match_the_reader(fused_pong_text):
+    """The two Mosaic custom calls carry the names their `pallas_call`s
+    give them, and those are the names the benchmark's kernel reader
+    (`uct_kernel_us_per_superstep`) matches in a device trace."""
+    from perfbench import spec
+
+    reader = spec.load_module(
+        spec.reader_path("uct_kernel_us_per_superstep"), "reader")
+    names = [m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+        fused_pong_text, re.M)]
+    assert len(names) == 2, names
+    assert all(reader.KERNELS.match(n) for n in names), names
+    assert sorted(n.split(".")[0] for n in names) == ["backup_arena",
+                                                      "select_arena"]
