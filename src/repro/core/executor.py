@@ -58,8 +58,9 @@ import numpy as np
 
 from repro.core import intree, ref_sequential as ref
 from repro.core.tree import (
-    NULL, TreeConfig, UCTree, arena_set_slot, arena_slot, init_arena,
-    init_tree, to_jax,
+    NULL, ROW_KEYS, TreeConfig, UCTree, arena_set_slot, arena_slot,
+    init_arena, init_rows, init_tree, pack_rows, pad_rows, read_slot_rows,
+    reset_slot_rows, row_bucket, to_jax, unpack_rows, write_slot_rows,
 )
 from repro.obs.trace import NULL_TRACER
 
@@ -79,7 +80,7 @@ class InTreeExecutor(Protocol):
     cfg: TreeConfig
     G: int
 
-    def reset_slot(self, g: int, root_num_actions: int) -> None: ...
+    def reset_slot(self, g: int, root_num_actions: int) -> int: ...
     def selection(self, active: np.ndarray, p: int): ...
     def insert(self, active: np.ndarray, sel) -> np.ndarray: ...
     def finalize(self, nodes, num_actions, terminal, prior_parent,
@@ -97,6 +98,7 @@ class InTreeExecutor(Protocol):
     def sel_to_host(self, sel) -> dict: ...
     def best_actions(self) -> np.ndarray: ...
     def sizes(self) -> np.ndarray: ...
+    def slot_rows(self, g: int) -> dict: ...
     def slot_snapshot(self, g: int) -> dict: ...
     def write_slot(self, g: int, arrays: dict) -> None: ...
     def block(self) -> None: ...
@@ -229,6 +231,7 @@ class JaxExecutor:
         self.cfg, self.G, self.variant = cfg, G, variant
         self._fused_variant = variant
         self.device = device
+        self._warm_buckets: set = set()
         self.trees = init_arena(cfg, G) if _trees is None else _trees
         if device is not None and _trees is None:
             from repro.models.sharding import put_on_device
@@ -300,9 +303,36 @@ class JaxExecutor:
         return fused.collect_supersteps(pend)
 
     # -- host-side slot access -----------------------------------------
-    def reset_slot(self, g: int, root_num_actions: int):
-        self.trees = arena_set_slot(
-            self.trees, g, init_tree(self.cfg, root_num_actions))
+    # Row-bounded (core.tree, "Row-bounded slot access"): the bucket of
+    # rows comes from the slot's size on the device.  Reading the sizes
+    # again after the pool's own sizes() read of the same arena is free
+    # (a jax.Array keeps its host copy).
+    def _bucket(self, rows: int) -> int:
+        """The bucket covering `rows`.  A bucket's first use compiles its
+        read, write and reset programs together, and the first of all
+        the best-action program too.  The arena is not donated, so it
+        stays as it was; each result is waited for and dropped before
+        the next program runs, so at most one spare arena exists."""
+        R = row_bucket(rows, self.cfg.X)
+        if R in self._warm_buckets:
+            return R
+        if not self._warm_buckets:
+            jax.block_until_ready(intree.best_root_action_arena(self.trees))
+        self._warm_buckets.add(R)
+        g = np.int32(0)
+        jax.block_until_ready(read_slot_rows(self.trees, g, R))
+        jax.block_until_ready(write_slot_rows(
+            self.trees, g, pack_rows(init_rows(self.cfg.Fp, R, 1, np))))
+        jax.block_until_ready(reset_slot_rows(self.trees, g, np.int32(1), R))
+        return R
+
+    def reset_slot(self, g: int, root_num_actions: int) -> int:
+        """Reset slot g to a fresh root on the device; only scalars
+        cross.  Returns the rows reset (covering the old size)."""
+        R = self._bucket(self.sizes()[g])
+        self.trees = reset_slot_rows(self.trees, np.int32(g),
+                                     np.int32(root_num_actions), R)
+        return R
 
     def sel_to_host(self, sel) -> dict:
         return _sel_to_host(sel)
@@ -314,12 +344,23 @@ class JaxExecutor:
     def sizes(self) -> np.ndarray:
         return np.asarray(jax.device_get(self.trees.size))
 
+    def slot_rows(self, g: int) -> dict:
+        """Slot g's first R rows (R covering its size), `size` and
+        `root`, copied down in one device_get."""
+        blocks = read_slot_rows(self.trees, np.int32(g),
+                                self._bucket(self.sizes()[g]))
+        return unpack_rows(jax.device_get(blocks))
+
     def slot_snapshot(self, g: int) -> dict:
-        one = jax.device_get(arena_slot(self.trees, g))
-        return {k: np.asarray(v) for k, v in dataclasses.asdict(one).items()}
+        return pad_rows(self.cfg, self.slot_rows(g))
 
     def write_slot(self, g: int, arrays: dict):
-        self.trees = arena_set_slot(self.trees, g, to_jax(UCTree(**arrays)))
+        """Write the rows of `arrays` (a bucket of them, or all X, at or
+        above the slot's old size), `size` and `root` into slot g in one
+        program."""
+        self._bucket(len(arrays["child"]))
+        self.trees = write_slot_rows(self.trees, np.int32(g),
+                                     pack_rows(arrays))
 
     def block(self):
         """Wait for every queued write to the arena (tracing fences)."""
@@ -476,9 +517,12 @@ class ReferenceExecutor:
                              None if dropped is None else dropped[g])
 
     # -- host-side slot access -----------------------------------------
-    def reset_slot(self, g: int, root_num_actions: int):
+    # The device executors' row-bounded surface, on host arrays.
+    def reset_slot(self, g: int, root_num_actions: int) -> int:
+        R = row_bucket(self.trees[g].size, self.cfg.X)
         self.trees[g] = ref.MutableTree.from_tree(
             init_tree(self.cfg, root_num_actions, xp=np))
+        return R
 
     def sel_to_host(self, sel) -> dict:
         return sel
@@ -490,12 +534,20 @@ class ReferenceExecutor:
     def sizes(self) -> np.ndarray:
         return np.array([t.size for t in self.trees], np.int32)
 
+    def slot_rows(self, g: int) -> dict:
+        t = self.trees[g]
+        R = row_bucket(t.size, self.cfg.X)
+        rows = {k: getattr(t, k)[:R].copy() for k in ROW_KEYS}
+        rows["size"], rows["root"] = np.int32(t.size), np.int32(t.root)
+        return rows
+
     def slot_snapshot(self, g: int) -> dict:
         return {k: np.asarray(v) for k, v in
                 dataclasses.asdict(self.trees[g].to_tree()).items()}
 
     def write_slot(self, g: int, arrays: dict):
-        self.trees[g] = ref.MutableTree.from_tree(UCTree(**arrays))
+        self.trees[g] = ref.MutableTree.from_tree(
+            UCTree(**pad_rows(self.cfg, arrays)))
 
     def block(self):
         pass

@@ -15,52 +15,57 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.core.tree import NULL, TreeConfig, UCTree, init_tree
+from repro.core.tree import NULL, TreeConfig, UCTree
 
 
 def reroot(cfg: TreeConfig, snap: dict, new_root: int):
-    """snap: numpy snapshot of a UCTree (executor.snapshot()).
-    Returns (new UCTree arrays as numpy dict, old_to_new index map)."""
-    X = cfg.X
+    """snap: numpy rows [0, N) of a UCTree's per-node arrays with N at or
+    above its size (executor.slot_rows(), or a full snapshot).  Returns
+    (the re-rooted tree's N rows with its `size` and `root`, the old ->
+    new id map over the N rows, NULL for dropped nodes)."""
     child = snap["child"]
-    # BFS from new_root
-    order = [int(new_root)]
-    seen = {int(new_root)}
-    for n in order:
-        for c in child[n]:
-            c = int(c)
-            if c != NULL and c not in seen:
-                seen.add(c)
-                order.append(c)
-    old2new = np.full(X, NULL, np.int32)
-    for new_id, old_id in enumerate(order):
-        old2new[old_id] = new_id
+    N = len(child)
+    # BFS from new_root, one level at a time: each level is its parents'
+    # unseen children in order, first occurrence kept -- a node queue's
+    # order, with the scan in numpy
+    seen = np.zeros(N, bool)
+    seen[new_root] = True
+    level = np.array([new_root], np.int64)
+    levels = [level]
+    while level.size:
+        kids = child[level].reshape(-1)
+        kids = kids[kids != NULL]
+        kids = kids[~seen[kids]]
+        _, first = np.unique(kids, return_index=True)
+        level = kids[np.sort(first)].astype(np.int64)
+        seen[level] = True
+        levels.append(level)
+    order = np.concatenate(levels)
+    n = len(order)
+    old2new = np.full(N, NULL, np.int32)
+    old2new[order] = np.arange(n, dtype=np.int32)
 
-    fresh = {k: np.array(v) for k, v in snap.items()
-             if k not in ("size", "root", "log_table")}
     out = {}
     for k in ("edge_N", "edge_W", "edge_VL", "edge_P",
               "num_expanded", "num_actions", "terminal",
               "node_N", "node_O"):
-        dst = np.zeros_like(fresh[k])
-        dst[: len(order)] = fresh[k][order]
+        dst = np.zeros_like(snap[k])
+        dst[:n] = snap[k][order]
         out[k] = dst
-    ch = np.full_like(fresh["child"], NULL)
-    remapped = np.where(child[order] != NULL,
-                        old2new[np.clip(child[order], 0, X - 1)], NULL)
-    ch[: len(order)] = remapped
+    ch = np.full_like(child, NULL)
+    kept = child[order]
+    ch[:n] = np.where(kept != NULL, old2new[np.clip(kept, 0, N - 1)], NULL)
     out["child"] = ch
-    nd = np.zeros_like(fresh["node_depth"])
-    nd[: len(order)] = fresh["node_depth"][order] - int(
-        fresh["node_depth"][new_root])
+    nd = np.zeros_like(snap["node_depth"])
+    nd[:n] = snap["node_depth"][order] - snap["node_depth"][new_root]
     out["node_depth"] = nd
-    out["size"] = np.int32(len(order))
+    out["size"] = np.int32(n)
     out["root"] = np.int32(0)
-    out["log_table"] = np.array(snap["log_table"])
     return out, old2new
 
 
 def reroot_tree(cfg: TreeConfig, snap: dict, new_root: int, xp):
     arrays, old2new = reroot(cfg, snap, new_root)
+    arrays["log_table"] = snap["log_table"]
     t = UCTree(**{k: xp.asarray(v) for k, v in arrays.items()})
     return t, old2new

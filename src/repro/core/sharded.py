@@ -173,9 +173,9 @@ class ShardedExecutor:
                 else self._pad_rows(dropped, lo, n, child.G, 0))
 
     # ---- host-side slot access (route to the owning shard) ----
-    def reset_slot(self, g: int, root_num_actions: int):
+    def reset_slot(self, g: int, root_num_actions: int) -> int:
         child, r = self._locate(int(g))
-        child.reset_slot(r, root_num_actions)
+        return child.reset_slot(r, root_num_actions)
 
     def best_actions(self) -> np.ndarray:
         return self._gather_rows([c.best_actions()
@@ -183,6 +183,10 @@ class ShardedExecutor:
 
     def sizes(self) -> np.ndarray:
         return self._gather_rows([c.sizes() for c, _, _ in self.shards])
+
+    def slot_rows(self, g: int) -> dict:
+        child, r = self._locate(int(g))
+        return child.slot_rows(r)
 
     def slot_snapshot(self, g: int) -> dict:
         child, r = self._locate(int(g))

@@ -53,14 +53,17 @@ class StateTable:
 
     def compact(self, old2new: np.ndarray):
         """Subtree-reusing flush (core.reroot): relocate surviving entries
-        to their new ids, invalidate the rest."""
+        to their new ids, invalidate the rest.  `old2new` covers the
+        first N entries, N at or above the tree's size; no entry above
+        the size is valid, so those above N are left as they are."""
+        n = len(old2new)
         keep = np.flatnonzero(old2new >= 0)
         new_ids = old2new[keep]
-        data = np.zeros_like(self.data)
-        valid = np.zeros_like(self.valid)
-        data[new_ids] = self.data[keep]
-        valid[new_ids] = self.valid[keep]
-        self.data, self.valid = data, valid
+        data, valid = self.data[keep], self.valid[keep]
+        self.data[:n] = 0
+        self.valid[:n] = False
+        self.data[new_ids] = data
+        self.valid[new_ids] = valid
         self.bytes_written += int(len(keep)) * self.state_bytes
 
     def nbytes(self) -> int:

@@ -27,11 +27,13 @@ allocation is an FPGA synthesis constraint with no TPU benefit).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import fixedpoint as fx
 
@@ -181,36 +183,42 @@ def make_log_table(x: int) -> np.ndarray:
     return t.astype(np.float32)
 
 
-def init_tree(cfg: TreeConfig, root_num_actions: int | None = None, xp=jnp) -> UCTree:
-    """Fresh tree with a single root node (id 0)."""
-    X, Fp = cfg.X, cfg.Fp
+def init_rows(Fp: int, R: int, root_num_actions, xp=jnp) -> dict:
+    """The first R rows of a fresh single-root tree, with its `size` and
+    `root`: NULL children, zero statistics, `root_num_actions` legal
+    actions at node 0.  Traceable with xp=jnp (`root_num_actions` may be
+    a tracer)."""
     i32 = xp.int32
-    z_e = xp.zeros((X, Fp), dtype=i32)
-    na = cfg.F if root_num_actions is None else int(root_num_actions)
-    num_actions = xp.zeros((X,), dtype=i32)
+    z_e = xp.zeros((R, Fp), dtype=i32)
+    num_actions = xp.zeros((R,), dtype=i32)
     if xp is np:
-        child = np.full((X, Fp), NULL, dtype=np.int32)
-        num_actions = num_actions.copy()
-        num_actions[0] = na
+        num_actions[0] = root_num_actions
+        size, root = np.int32(1), np.int32(0)
     else:
-        child = xp.full((X, Fp), NULL, dtype=i32)
-        num_actions = num_actions.at[0].set(na)
-    return UCTree(
-        child=child,
+        num_actions = num_actions.at[0].set(root_num_actions)
+        size, root = xp.asarray(1, dtype=i32), xp.asarray(0, dtype=i32)
+    return dict(
+        child=xp.full((R, Fp), NULL, dtype=i32),
         edge_N=z_e,
         edge_W=z_e,
         edge_VL=z_e,
         edge_P=z_e,
-        node_N=xp.zeros((X,), dtype=i32),
-        node_O=xp.zeros((X,), dtype=i32),
-        num_expanded=xp.zeros((X,), dtype=i32),
+        node_N=xp.zeros((R,), dtype=i32),
+        node_O=xp.zeros((R,), dtype=i32),
+        num_expanded=xp.zeros((R,), dtype=i32),
         num_actions=num_actions,
-        node_depth=xp.zeros((X,), dtype=i32),
-        terminal=xp.zeros((X,), dtype=i32),
-        size=xp.asarray(1, dtype=i32) if xp is jnp else np.int32(1),
-        root=xp.asarray(0, dtype=i32) if xp is jnp else np.int32(0),
-        log_table=xp.asarray(make_log_table(X)),
+        node_depth=xp.zeros((R,), dtype=i32),
+        terminal=xp.zeros((R,), dtype=i32),
+        size=size,
+        root=root,
     )
+
+
+def init_tree(cfg: TreeConfig, root_num_actions: int | None = None, xp=jnp) -> UCTree:
+    """Fresh tree with a single root node (id 0)."""
+    na = cfg.F if root_num_actions is None else int(root_num_actions)
+    return UCTree(**init_rows(cfg.Fp, cfg.X, na, xp),
+                  log_table=xp.asarray(make_log_table(cfg.X)))
 
 
 def to_numpy(tree: UCTree) -> UCTree:
@@ -263,3 +271,102 @@ def where_trees(mask, new: UCTree, old: UCTree) -> UCTree:
         m = jnp.reshape(jnp.asarray(mask), mask.shape + (1,) * (a.ndim - 1))
         return jnp.where(m, a, b)
     return jax.tree.map(pick, new, old)
+
+
+# --------------------------------------------------------------------------
+# Row-bounded slot access (move commit, admission)
+# --------------------------------------------------------------------------
+#
+# Node ids are allocated contiguously from 0, so a slot's live rows are
+# [0, size), and every row at or above `size` holds its initial value:
+# insertion writes only `child`, `node_depth` and `num_actions` of a new
+# row and relies on its statistics being zero and its children NULL.  A
+# read, write or reset therefore touches only a bucket of R rows covering
+# the sizes involved: the next power of two, at least ROW_FLOOR, at most
+# X.  Each is one compiled program per R with the slot index traced; the
+# rows cross as one block of the edge arrays, one of the node arrays and
+# the pair (size, root).  `log_table` depends on X alone and is never
+# read, written or reset here.
+
+ROW_KEYS = ("child", "edge_N", "edge_W", "edge_VL", "edge_P", "node_N",
+            "node_O", "num_expanded", "num_actions", "node_depth",
+            "terminal")
+_EDGE_KEYS, _NODE_KEYS = ROW_KEYS[:5], ROW_KEYS[5:]
+# A bucket's first use compiles its three programs, and each compiled
+# bucket keeps about 0.6 MB of program on a TPU v5e, so the floor sits
+# where the common trees never leave it: Pong's live trees at 128
+# simulations a move hold about 90-200 nodes.
+ROW_FLOOR = 512
+
+
+def row_bucket(rows: int, X: int) -> int:
+    """Rows a slot operation touches to cover `rows` live rows."""
+    return min(X, max(ROW_FLOOR, 1 << max(int(rows) - 1, 0).bit_length()))
+
+
+def pack_rows(rows: dict, xp=np) -> tuple:
+    """Rows dict (R rows of ROW_KEYS, `size`, `root`) -> the three int32
+    blocks that cross: edges [5, R, Fp], nodes [6, R], (size, root)."""
+    i32 = xp.int32
+    return (xp.stack([xp.asarray(rows[k], i32) for k in _EDGE_KEYS]),
+            xp.stack([xp.asarray(rows[k], i32) for k in _NODE_KEYS]),
+            xp.stack([xp.asarray(rows["size"], i32),
+                      xp.asarray(rows["root"], i32)]))
+
+
+def unpack_rows(blocks) -> dict:
+    """Inverse of pack_rows (views into the blocks, numpy or traced)."""
+    edges, nodes, head = blocks
+    rows = {k: edges[i] for i, k in enumerate(_EDGE_KEYS)}
+    rows.update({k: nodes[i] for i, k in enumerate(_NODE_KEYS)})
+    rows["size"], rows["root"] = head[0], head[1]
+    return rows
+
+
+def pad_rows(cfg: TreeConfig, rows: dict) -> dict:
+    """Full-width numpy snapshot of a slot from its first R rows: the
+    rows above hold their initial values (the invariant above)."""
+    full = init_rows(cfg.Fp, cfg.X, 0, np)
+    R = len(rows["child"])
+    for k in ROW_KEYS:
+        full[k] = np.array(full[k])   # init_rows shares one zero block
+        full[k][:R] = rows[k]
+    full["size"] = np.asarray(rows["size"], np.int32)
+    full["root"] = np.asarray(rows["root"], np.int32)
+    full["log_table"] = make_log_table(cfg.X)
+    return full
+
+
+def _put_rows(arena: UCTree, g, rows: dict) -> UCTree:
+    def put(a, r):
+        return lax.dynamic_update_slice(
+            a, r[None].astype(a.dtype), (g,) + (0,) * (a.ndim - 1))
+    new = {k: put(getattr(arena, k), rows[k]) for k in ROW_KEYS}
+    new["size"] = arena.size.at[g].set(rows["size"])
+    new["root"] = arena.root.at[g].set(rows["root"])
+    return dataclasses.replace(arena, **new)
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def read_slot_rows(arena: UCTree, g, R: int) -> tuple:
+    """Slot g's first R rows, `size` and `root` (pack_rows' blocks)."""
+    def take(a):
+        start = (g,) + (0,) * (a.ndim - 1)
+        return lax.dynamic_slice(a, start, (1, R) + a.shape[2:])[0]
+    rows = {k: take(getattr(arena, k)) for k in ROW_KEYS}
+    rows["size"], rows["root"] = arena.size[g], arena.root[g]
+    return pack_rows(rows, jnp)
+
+
+@jax.jit
+def write_slot_rows(arena: UCTree, g, blocks: tuple) -> UCTree:
+    """Write pack_rows' blocks of R rows, `size` and `root` into slot g;
+    rows at or above R are left as they are."""
+    return _put_rows(arena, g, unpack_rows(blocks))
+
+
+@functools.partial(jax.jit, static_argnums=3)
+def reset_slot_rows(arena: UCTree, g, root_num_actions, R: int) -> UCTree:
+    """Reset slot g's first R rows to a fresh single-root tree."""
+    return _put_rows(arena, g, init_rows(arena.child.shape[2], R,
+                                         root_num_actions, jnp))

@@ -7,7 +7,10 @@ eviction / retirement / expiry counts, expansion batch calls, compaction
 decisions, committed moves (``service_moves_committed_total``) and the
 bytes that cross between host and device on the fused path
 (``service_host_transfer_bytes_total{site,dir}``: site upload, readback,
-snapshot or write; dir h2d or d2h) — and ``render()`` emits one
+snapshot or write; dir h2d or d2h), the arena rows the move commit and
+admission touch (``service_slot_rows_total{op}`` over
+``service_slot_ops_total{op}``, op snapshot, write or reset) — and
+``render()`` emits one
 snapshot in Prometheus exposition format (the text format every scrape
 pipeline ingests):
 

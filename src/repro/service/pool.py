@@ -130,7 +130,7 @@ from repro.core.executor import CompactionSession, make_intree_executor
 from repro.core.expand import ExpansionEngine
 from repro.core.mcts import Environment, SimulationBackend
 from repro.core.state_table import StateTable
-from repro.core.tree import NULL, TreeConfig, bucket_key
+from repro.core.tree import NULL, TreeConfig, bucket_key, pad_rows
 from repro.obs.metrics import NULL_REGISTRY
 from repro.obs.trace import NULL_TRACER
 
@@ -440,6 +440,20 @@ class ArenaPool:
                 bucket=label, site=site, dir=direction)
             for site, direction in (("upload", "h2d"), ("readback", "d2h"),
                                     ("snapshot", "d2h"), ("write", "h2d"))}
+        # the arena rows a slot read, write or reset touched (the bucket
+        # covering the slot's size, core.tree "Row-bounded slot access"),
+        # and how many such operations ran
+        self._m_slot_rows = {
+            op: reg.counter(
+                "service_slot_rows_total",
+                "arena rows touched by slot snapshots, writes and resets",
+                bucket=label, op=op)
+            for op in ("snapshot", "write", "reset")}
+        self._m_slot_ops = {
+            op: reg.counter(
+                "service_slot_ops_total",
+                "slot snapshots, writes and resets", bucket=label, op=op)
+            for op in ("snapshot", "write", "reset")}
         # host-expansion engine: "loop" per-worker env.step, "vector" ONE
         # flattened step_batch over all slots' pending expansions, "pool"
         # the process-pool scalar fallback (core.expand) — bit-identical.
@@ -614,7 +628,7 @@ class ArenaPool:
             with self.trace.span("admit", cat="request", tid=self._track,
                                  uid=req.uid, slot=g,
                                  shard=g // self.shard_G, wait=wait):
-                self.exec.reset_slot(g, na)   # builds the tree on device
+                self._count_slot_op("reset", self.exec.reset_slot(g, na))
                 self.sts[g].flush(s0)
                 if self.trace.enabled:
                     self.exec.block()
@@ -767,12 +781,16 @@ class ArenaPool:
     def _slot_snapshot(self, g: int) -> dict:
         """Snapshot through the session: a dirty sub-arena is scattered
         back first (the snapshot must see the latest supersteps), then the
-        full arena is read as usual."""
+        full arena's rows covering slot g's tree are read."""
         ses = self._session
         if ses is not None and ses.owns(int(g)) and ses.sync():
             self.stats.session_scatters += 1
             self._m_scatters.inc()
-        return self.exec.slot_snapshot(g)
+        return self.exec.slot_rows(g)
+
+    def _count_slot_op(self, op: str, rows: int):
+        self._m_slot_rows[op].inc(rows)
+        self._m_slot_ops[op].inc()
 
     def _invalidate_session(self, g: int):
         """A host-side write (reroot / reset / eviction) is about to touch
@@ -1489,6 +1507,7 @@ class ArenaPool:
         with self.trace.span("commit-snapshot", cat="request",
                              tid=self._track):
             snap = self._slot_snapshot(g)
+            self._count_slot_op("snapshot", len(snap["child"]))
             self._m_bytes["snapshot"].inc(
                 sum(v.nbytes for v in snap.values()))
         # every path below rewrites or frees this slot on the full arena,
@@ -1510,7 +1529,7 @@ class ArenaPool:
         if last:
             slot.res.terminal = bool(term)
             if slot.req.keep_tree:
-                slot.res.tree_snapshot = snap
+                slot.res.tree_snapshot = pad_rows(self.cfg, snap)
             self._finish(slot.res)
             self.slots[g] = None
             return True
@@ -1530,11 +1549,13 @@ class ArenaPool:
                              tid=self._track):
             if reuse:
                 self.exec.write_slot(g, arrays)
+                self._count_slot_op("write", len(arrays["child"]))
                 self._m_bytes["write"].inc(
                     sum(np.asarray(v).nbytes for v in arrays.values()))
                 slot.prev_size = int(arrays["size"])
             else:   # the fresh tree is built on the device
-                self.exec.reset_slot(g, max(env.num_actions(new_state), 1))
+                self._count_slot_op("reset", self.exec.reset_slot(
+                    g, max(env.num_actions(new_state), 1)))
                 slot.prev_size = 1
             if self.trace.enabled:
                 self.exec.block()

@@ -324,7 +324,7 @@ def _direct_children(spans, parent):
 
 
 def test_fused_run_splits_dispatch_and_commit_into_spans():
-    import jax
+    import dataclasses
 
     from repro.core.tree import init_arena
 
@@ -366,16 +366,19 @@ def test_fused_run_splits_dispatch_and_commit_into_spans():
     assert last.count(True) == len(done)
 
     # transfer bytes from the shapes: per dispatch the state image (and
-    # budgets) up, the states and two size rows down; per move one
-    # slot's UCT down, per re-rooted move one slot's UCT up
+    # budgets) up, the states and two size rows down; per move the rows
+    # covering one slot's tree down, per re-rooted move up (here all
+    # X=256 rows: the smallest bucket is larger), with size and root but
+    # never the log table
     label = f"X{FUSED_CFG.X}_D{FUSED_CFG.D}_Fp{FUSED_CFG.Fp}"
     moved = {site: reg.get("service_host_transfer_bytes_total",
                            bucket=label, site=site, dir=d).value
              for site, d in (("upload", "h2d"), ("readback", "d2h"),
                              ("snapshot", "d2h"), ("write", "h2d"))}
     image = FUSED_G * FUSED_CFG.X * 8 * 4      # state (8,) float32
-    slot = sum(a.nbytes
-               for a in jax.tree.leaves(init_arena(FUSED_CFG, 1)))
+    slot = sum(a.nbytes for k, a in
+               dataclasses.asdict(init_arena(FUSED_CFG, 1)).items()
+               if k != "log_table")
     dispatches = stats.fused_dispatches
     moves = reg.get("service_moves_committed_total", bucket=label).value
     rerooted = sum(e["args"]["reuse"] for e in spans
