@@ -4,10 +4,21 @@ One scoring routine shared — verbatim — by the sequential numpy oracle,
 the batched jit ops and the Pallas kernel reference.  All inputs are
 integers (counts + Qm.16 sums); all transcendental inputs come from the
 shared ln-table; every float op used (convert / divide / sqrt / add /
-multiply-by-pow2 / round) is IEEE-754 correctly rounded, so numpy-f32 and
-jax-f32 produce bit-identical scores and therefore identical argmax
-decisions.  This is how the paper's "exact same outputs as a CPU-only
-system" claim survives vectorization.
+multiply / round) is IEEE-754 correctly rounded, so numpy-f32 and jax-f32
+produce bit-identical scores and therefore identical argmax decisions.
+This is how the paper's "exact same outputs as a CPU-only system" claim
+survives vectorization.
+
+Two backends need help for that.  A TPU's float32 divide and sqrt may
+miss the nearest float by an ulp, so off numpy `div_rn` / `sqrt_rn`
+correct the backend's result to the nearest one from exact products
+(Dekker: float32 multiplies and adds only).  And XLA may reassociate a
+product with a broadcast scalar (``(c * P) * sqrt_ns`` into
+``P * (c * sqrt_ns)``), or fuse a product into the add after it (one
+rounding for two); the PUCT numerator and the UCT bonus pass through a
+select, which neither rewrite crosses.  One such miss, on an edge whose
+score ties another's to the Qm.16 unit, changes an argmax: a different
+tree.
 
 Shapes: edge inputs are ``[..., Fp]``; node inputs broadcast as ``[..., 1]``.
 Returns int32 fixed-point scores ``[..., Fp]`` where invalid lanes are
@@ -21,6 +32,83 @@ import numpy as np
 
 from repro.core import fixedpoint as fx
 from repro.core.tree import NULL, TreeConfig
+
+# Veltkamp's split of a float32 into two halves of 12 significant bits
+_SPLIT = 4097.0
+
+
+def _exact_product(a, b, xp):
+    """(p, e) with p = fl(a * b) and p + e = a * b exactly (Dekker), for
+    products that neither overflow nor underflow."""
+    f32 = xp.float32
+
+    def split(x):
+        c = f32(_SPLIT) * x
+        hi = c - (c - x)
+        return hi, x - hi
+
+    p = a * b
+    ah, al = split(a)
+    bh, bl = split(b)
+    return p, ((ah * bh - p) + ah * bl + al * bh) + al * bl
+
+
+# A backend's divide or sqrt lands within two ulps of the nearest float;
+# each round moves one ulp toward it.
+_ROUNDS = 2
+
+
+def nearest_quotient(a, b, q, xp):
+    """The float32 nearest a / b, from q within two ulps of it.  a - q*b
+    is exact (a - p by Sterbenz, the error e from Dekker), and so is the
+    step d of one ulp; the quotient passes the midpoint q + d/2 exactly
+    when |a - q*b| > |d*b| / 2, never equal (no quotient of floats is a
+    midpoint)."""
+    f32 = xp.float32
+    for _ in range(_ROUNDS):
+        p, e = _exact_product(q, b, xp)
+        r = (a - p) - e
+        up = (r > 0) == (b > 0)
+        q2 = xp.nextafter(q, xp.where(up, f32(np.inf), f32(-np.inf)))
+        past = xp.abs(r) - xp.abs((q2 - q) * b) * f32(0.5) > 0
+        q = xp.where((r != 0) & past, q2, q)
+    return q
+
+
+def nearest_root(x, s, xp):
+    """The float32 nearest sqrt(x), from s within two ulps of it.  With
+    r = x - s*s exact and d the ulp step toward the root, the root passes
+    the midpoint m = s + d/2 exactly when x - m*m = (r - s*d) - d*d/4 has
+    the sign of d; r - s*d is split into its rounded value and error
+    (Knuth's two-sum), so that sign is exact."""
+    f32 = xp.float32
+    for _ in range(_ROUNDS):
+        p, e = _exact_product(s, s, xp)
+        r = (x - p) - e
+        s2 = xp.nextafter(s, xp.where(r > 0, f32(np.inf), f32(-np.inf)))
+        d = s2 - s
+        sd = s * d
+        y = r - sd
+        yv = y - r
+        y_err = (r - (y - yv)) - (sd + yv)
+        z = y + (y_err - d * d * f32(0.25))
+        q = (r != 0) & (z != 0) & ((z > 0) == (d > 0))
+        s = xp.where(q, s2, s)
+    return s
+
+
+def div_rn(a, b, xp=np):
+    """a / b in float32, rounded to nearest on every backend."""
+    if xp is np:
+        return a / b
+    return nearest_quotient(a, b, a / b, xp)
+
+
+def sqrt_rn(x, xp=np):
+    """sqrt(x) in float32, rounded to nearest on every backend."""
+    if xp is np:
+        return np.sqrt(x)
+    return nearest_root(x, xp.sqrt(x), xp)
 
 
 def edge_scores_fx(
@@ -61,16 +149,18 @@ def edge_scores_fx(
         log_ns = xp.take(log_table, ns, axis=0)  # [..., 1] f32 (shared table)
 
     if cfg.score_fn == "uct":
-        q = (edge_W.astype(f32) * f32(fx.FX_INV_SCALE)) / ne_safe
-        u = f32(cfg.beta) * xp.sqrt(log_ns / ne_safe)
+        q = div_rn(edge_W.astype(f32) * f32(fx.FX_INV_SCALE), ne_safe, xp)
+        u = xp.where(valid, f32(cfg.beta)
+                     * sqrt_rn(div_rn(log_ns, ne_safe, xp), xp), f32(0.0))
         base = fx.encode(q + u, xp=xp)
         base = xp.where(ne == 0, fx.FX_FORCE_EXPLORE, base)
     else:  # puct: Q + c * P * sqrt(Ns) / (1 + Ne); Q := 0 when unvisited
-        q = (edge_W.astype(f32) * f32(fx.FX_INV_SCALE)) / ne_safe
+        q = div_rn(edge_W.astype(f32) * f32(fx.FX_INV_SCALE), ne_safe, xp)
         q = xp.where(ne == 0, f32(0.0), q)
-        sqrt_ns = xp.sqrt(ns.astype(f32))
+        sqrt_ns = sqrt_rn(ns.astype(f32), xp)
         p_f = edge_P.astype(f32) * f32(fx.FX_INV_SCALE)
-        u = f32(cfg.beta) * p_f * sqrt_ns / (f32(1.0) + ne.astype(f32))
+        cp = xp.where(valid, f32(cfg.beta) * p_f, f32(0.0))
+        u = div_rn(cp * sqrt_ns, f32(1.0) + ne.astype(f32), xp)
         base = fx.encode(q + u, xp=xp)
 
     if cfg.vl_mode == "constant":

@@ -10,8 +10,6 @@ training example.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -52,8 +50,10 @@ def apply(params: dict, boards: jax.Array):
     return values, logits
 
 
-@functools.partial(jax.jit, static_argnums=())
-def _infer(params, boards):
+@jax.jit
+def policy_value_forward(params, boards):
+    """The forward as one jitted program, under a stable name: a
+    profile's "XLA Modules" line shows it as `jit_policy_value_forward`."""
     return apply(params, boards)
 
 
@@ -82,7 +82,8 @@ class NNSimBackend:
         B = len(states)
         boards = states[:, 3 : 3 + _CELLS].reshape(B, _BOARD, _BOARD)
         canon = boards * states[:, 0:1][:, :, None]
-        return _infer(self.params, jnp.asarray(canon, jnp.float32))
+        return policy_value_forward(self.params,
+                                    jnp.asarray(canon, jnp.float32))
 
     def finalize(self, token, states: np.ndarray):
         """Block on a dispatched forward and post-process: terminal rows

@@ -14,8 +14,9 @@ reads as it did before the spans were added.
                expand / simulate / backup / compact-gather /
                compact-scatter; the fused dispatch's admit /
                fused-upload / fused-run / fused-readback; a move's
-               move-commit with commit-snapshot / commit-reroot /
-               commit-write; overlap-wait-env / overlap-wait-device),
+               move-commit with commit-snapshot / commit-emit /
+               commit-reroot / commit-write; overlap-wait-env /
+               overlap-wait-device),
                with explicit block_until_ready fencing when tracing is
                live so device time is attributed honestly, + async
                request-lifecycle spans (submit -> supersteps -> result /

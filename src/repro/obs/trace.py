@@ -55,15 +55,20 @@ Spans of the serving stack (service/pool.py), on each pool's track:
       backup, move-commit
   fused-dispatch > admit, fused-upload, fused-run,    fused K-superstep
       fused-readback, move-commit                     dispatch
-  move-commit > commit-snapshot, commit-reroot,       one committed move
-      commit-write
+  move-commit > commit-snapshot, commit-emit,         one committed move
+      commit-reroot, commit-write
+  simulate > sim-cache, sim-flush, sim-finalize       the simulation layer
+                                                      (repro.sim), on the
+                                                      caller's track
   overlap-wait-env / overlap-wait-device              overlap mode's two
                                                       blocking waits
   compact-gather / compact-scatter                    compaction sessions
 
 A span that ends on a device transfer or write is fenced with
 ``block_until_ready`` only while tracing, so its time is the transfer's;
-untraced runs get no fence.
+untraced runs get no fence.  The simulation layer fences nothing:
+``sim-flush`` enqueues the network's forward, ``sim-finalize`` holds
+the host's wait for its outputs.
 """
 
 from __future__ import annotations
@@ -79,12 +84,14 @@ class Span:
     """An open span: the token begin() hands out and end() consumes.
     Carries everything the eventual "X" record needs except duration."""
 
-    __slots__ = ("name", "cat", "tid", "ts", "args", "depth", "note")
+    __slots__ = ("name", "cat", "tid", "ts", "args", "depth", "note",
+                 "seq")
 
-    def __init__(self, name, cat, tid, ts, args, depth, note=None):
+    def __init__(self, name, cat, tid, ts, args, depth, note=None, seq=0):
         self.name, self.cat, self.tid = name, cat, tid
         self.ts, self.args, self.depth = ts, args, depth
         self.note = note    # the span's open profiler annotation
+        self.seq = seq      # order of begin() calls, for current_track()
 
 
 class _SpanCtx:
@@ -142,6 +149,7 @@ class Tracer:
         self._stacks: dict[int, list] = {}   # tid -> open-span stack
         self._tracks: dict[str, int] = {}    # track name -> tid
         self._next_tid = 0
+        self._begun = 0                      # begin() calls so far
         # metadata events (process/track names): tiny, never dropped
         self._meta: list[dict] = [{
             "ph": "M", "name": "process_name", "pid": pid, "tid": 0,
@@ -185,12 +193,17 @@ class Tracer:
 
     # ---- complete spans ----
     def begin(self, name: str, cat: str = "", tid: int = 0, **args) -> Span:
+        # the clock is read first here and last in end(), so a span's
+        # time holds its own bookkeeping and profiler annotation, not the
+        # gaps between its parent's children
+        ts = self._now_us()
         stack = self._stacks.setdefault(tid, [])
         note = None
         if self._annotation is not None:
             note = self._annotation(name)
             note.__enter__()
-        tok = Span(name, cat, tid, self._now_us(), args, len(stack), note)
+        self._begun += 1
+        tok = Span(name, cat, tid, ts, args, len(stack), note, self._begun)
         stack.append(tok)
         return tok
 
@@ -203,13 +216,13 @@ class Tracer:
             f"{tok.name!r} but "
             f"{stack[-1].name if stack else '<empty>'!r} is open")
         stack.pop()
-        dur = self._now_us() - tok.ts
         if tok.note is not None:
             tok.note.__exit__(None, None, None)
         tok.args.update(args)
         self._push({
             "ph": "X", "name": tok.name, "cat": tok.cat, "pid": self.pid,
-            "tid": tok.tid, "ts": tok.ts, "dur": dur, "args": tok.args})
+            "tid": tok.tid, "ts": tok.ts, "args": tok.args,
+            "dur": self._now_us() - tok.ts})
 
     def span(self, name: str, cat: str = "", tid: int = 0,
              **args) -> _SpanCtx:
@@ -218,6 +231,17 @@ class Tracer:
     def open_depth(self, tid: int = 0) -> int:
         """How many spans are currently open on a track (tests)."""
         return len(self._stacks.get(tid, ()))
+
+    def current_track(self) -> int:
+        """The track of the innermost open span (the open one begun
+        last), or 0 when none is open.  A layer that serves many callers
+        (repro.sim's server and cache) puts its spans there, so they nest
+        in the span of the caller that drove them."""
+        tid, last = 0, 0
+        for t, stack in self._stacks.items():
+            if stack and stack[-1].seq > last:
+                tid, last = t, stack[-1].seq
+        return tid
 
     # ---- instants ----
     def instant(self, name: str, cat: str = "", tid: int = 0, **args):
@@ -308,6 +332,9 @@ class NullTracer:
         return _NULL_SPAN_CTX
 
     def open_depth(self, tid: int = 0) -> int:
+        return 0
+
+    def current_track(self) -> int:
         return 0
 
     def instant(self, name, cat="", tid=0, **args):

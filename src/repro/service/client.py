@@ -246,6 +246,9 @@ class SearchClient:
         # so metrics() renders one coherent snapshot
         if self.registry is not None and hasattr(sim, "bind_metrics"):
             sim.bind_metrics(self.registry)
+        # ... and their spans onto this client's tracer
+        if self.tracer is not None and hasattr(sim, "bind_tracer"):
+            sim.bind_tracer(self.tracer)
         self.core = SchedulerCore(
             env, sim, G, p, executor=executor, default_cfg=default_cfg,
             policy=policy, fuse_across_pools=fuse_across_pools,

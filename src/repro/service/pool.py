@@ -1510,29 +1510,31 @@ class ArenaPool:
             self._count_slot_op("snapshot", len(snap["child"]))
             self._m_bytes["snapshot"].inc(
                 sum(v.nbytes for v in snap.values()))
-        # every path below rewrites or frees this slot on the full arena,
-        # so a resident sub-arena spanning it must end now (its final
-        # state was just scattered by the snapshot sync)
-        self._invalidate_session(g)
-        root = int(snap["root"])
-        counts = np.array(snap["edge_N"][root][: slot.cfg.F], np.int64)
-        new_state, reward, term = env.step(slot.root_state, a)
-        slot.res.actions.append(a)
-        slot.res.rewards.append(float(reward))
-        slot.res.visit_counts.append(counts)
-        slot.moves_done += 1
-        last = bool(term) or slot.moves_done >= slot.req.moves
-        if self.move_listener is not None:
-            self.move_listener(MoveEvent(
-                uid=slot.req.uid, move_index=slot.moves_done - 1, action=a,
-                reward=float(reward), visit_counts=counts, last=last))
-        if last:
-            slot.res.terminal = bool(term)
-            if slot.req.keep_tree:
-                slot.res.tree_snapshot = pad_rows(self.cfg, snap)
-            self._finish(slot.res)
-            self.slots[g] = None
-            return True
+            # every path below rewrites or frees this slot on the full
+            # arena, so a resident sub-arena spanning it must end now (its
+            # final state was just scattered by the snapshot sync)
+            self._invalidate_session(g)
+        with self.trace.span("commit-emit", cat="request", tid=self._track):
+            root = int(snap["root"])
+            counts = np.array(snap["edge_N"][root][: slot.cfg.F], np.int64)
+            new_state, reward, term = env.step(slot.root_state, a)
+            slot.res.actions.append(a)
+            slot.res.rewards.append(float(reward))
+            slot.res.visit_counts.append(counts)
+            slot.moves_done += 1
+            last = bool(term) or slot.moves_done >= slot.req.moves
+            if self.move_listener is not None:
+                self.move_listener(MoveEvent(
+                    uid=slot.req.uid, move_index=slot.moves_done - 1,
+                    action=a, reward=float(reward), visit_counts=counts,
+                    last=last))
+            if last:
+                slot.res.terminal = bool(term)
+                if slot.req.keep_tree:
+                    slot.res.tree_snapshot = pad_rows(self.cfg, snap)
+                self._finish(slot.res)
+                self.slots[g] = None
+                return True
         # long-lived request: next move on the same slot
         slot.root_state = new_state
         slot.move_supersteps = 0

@@ -16,7 +16,10 @@ canonical perspective is part of the key for free.)
 Hit/miss/evict counters live in the MetricsRegistry (``sim_cache_*``);
 ``bind_metrics`` rebinds them onto a client's registry after
 construction (SearchClient does this for any ``sim_backend`` that
-exposes the hook).
+exposes the hook).  ``bind_tracer`` binds a Tracer the same way: a
+``sim-cache`` span covers a batch's lookups (at submit) and its inserts
+and hit scatter (at collect), on the track of the caller's innermost
+open span; the inner backend gets the same tracer.
 """
 
 from __future__ import annotations
@@ -27,6 +30,7 @@ from typing import Optional
 import numpy as np
 
 from repro.obs.metrics import NULL_REGISTRY
+from repro.obs.trace import NULL_TRACER
 
 __all__ = ["SimCache", "CachedSimBackend"]
 
@@ -115,19 +119,28 @@ class CachedSimBackend:
     def __init__(self, inner, capacity: int = 4096, metrics=None):
         self.inner = inner
         self.cache = SimCache(capacity, metrics)
+        self.trace = NULL_TRACER
 
     def bind_metrics(self, metrics) -> None:
         self.cache.bind_metrics(metrics)
         if hasattr(self.inner, "bind_metrics"):
             self.inner.bind_metrics(metrics)
 
+    def bind_tracer(self, tracer) -> None:
+        self.trace = NULL_TRACER if tracer is None else tracer
+        if hasattr(self.inner, "bind_tracer"):
+            self.inner.bind_tracer(tracer)
+
     # ---- non-blocking split ----
     def submit(self, states: np.ndarray, priority: Optional[str] = None):
         states = np.asarray(states)
-        keys = [SimCache.key(states[i]) for i in range(len(states))]
-        hits = [self.cache.get(k) for k in keys]
-        miss_idx = [i for i, h in enumerate(hits) if h is None]
-        miss_states = states[np.asarray(miss_idx)] if miss_idx else None
+        with self.trace.span("sim-cache", cat="sim",
+                             tid=self.trace.current_track(),
+                             rows=len(states)):
+            keys = [SimCache.key(states[i]) for i in range(len(states))]
+            hits = [self.cache.get(k) for k in keys]
+            miss_idx = [i for i, h in enumerate(hits) if h is None]
+            miss_states = states[np.asarray(miss_idx)] if miss_idx else None
         inner = None
         if miss_idx:
             if callable(getattr(self.inner, "submit", None)):
@@ -139,18 +152,6 @@ class CachedSimBackend:
                               len(states))
 
     def collect(self, pending: _PendingCached):
-        values = np.zeros(pending.n, np.float32)
-        priors = None
-
-        def _prior_row(row, pr):
-            nonlocal priors
-            if pr is None:
-                return
-            if priors is None:
-                priors = np.zeros((pending.n, len(pr)),
-                                  np.asarray(pr).dtype)
-            priors[row] = pr
-
         if pending.miss_idx:
             if callable(getattr(self.inner, "collect", None)) \
                     and pending.inner is not None:
@@ -160,15 +161,30 @@ class CachedSimBackend:
                                              pending.miss_states)
             else:
                 mv, mp = self.inner.evaluate(pending.miss_states)
+        with self.trace.span("sim-cache", cat="sim",
+                             tid=self.trace.current_track(),
+                             rows=pending.n):
+            values = np.zeros(pending.n, np.float32)
+            priors = None
+
+            def _prior_row(row, pr):
+                nonlocal priors
+                if pr is None:
+                    return
+                if priors is None:
+                    priors = np.zeros((pending.n, len(pr)),
+                                      np.asarray(pr).dtype)
+                priors[row] = pr
+
             for j, row in enumerate(pending.miss_idx):
                 pr = None if mp is None else mp[j]
                 values[row] = mv[j]
                 _prior_row(row, pr)
                 self.cache.put(pending.keys[row], mv[j], pr)
-        for row, hit in enumerate(pending.hits):
-            if hit is not None:
-                values[row] = hit[0]
-                _prior_row(row, hit[1])
+            for row, hit in enumerate(pending.hits):
+                if hit is not None:
+                    values[row] = hit[0]
+                    _prior_row(row, hit[1])
         return values, priors
 
     # ---- blocking protocol surface ----

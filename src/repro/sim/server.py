@@ -32,7 +32,11 @@ Mechanics:
 
 Telemetry (``sim_server_*``) lands in the MetricsRegistry passed at
 construction or bound later via ``bind_metrics`` (SearchClient binds its
-own registry onto any sim backend exposing the hook).
+own registry onto any sim backend exposing the hook).  Spans go to the
+Tracer bound the same way (``bind_tracer``): ``sim-flush`` (one
+microbatch assembled, padded and its forward dispatched) and
+``sim-finalize`` (the wait on one microbatch's outputs and their scatter
+to the tickets), each on the track of the caller's innermost open span.
 """
 
 from __future__ import annotations
@@ -44,6 +48,7 @@ from typing import Optional
 import numpy as np
 
 from repro.obs.metrics import NULL_REGISTRY
+from repro.obs.trace import NULL_TRACER
 
 __all__ = ["PRIORITY_CLASSES", "PendingBatch", "SimServer"]
 
@@ -102,6 +107,10 @@ class SimServer:
         self._can_dispatch = callable(getattr(backend, "dispatch", None)) \
             and callable(getattr(backend, "finalize", None))
         self.bind_metrics(metrics)
+        self.trace = NULL_TRACER
+
+    def bind_tracer(self, tracer) -> None:
+        self.trace = NULL_TRACER if tracer is None else tracer
 
     def bind_metrics(self, metrics) -> None:
         reg = NULL_REGISTRY if metrics is None else metrics
@@ -182,36 +191,43 @@ class SimServer:
         pad it to max_batch with copies of its first row — always a
         valid state, and row independence keeps real rows unaffected —
         and start the backend forward."""
-        rows, dst = [], []
-        for cls in PRIORITY_CLASSES:
-            q = self._queues[cls]
-            while q and len(rows) < self.max_batch:
-                state, ticket, i, _ = q.popleft()
-                rows.append(state)
-                dst.append((ticket, i))
-        if not rows:
-            return
-        self._queued -= len(rows)
-        n_real = len(rows)
-        if n_real < self.max_batch:
-            rows.extend([rows[0]] * (self.max_batch - n_real))
-            self._m_partial.inc()
-        states = np.stack(rows)
-        token = self.backend.dispatch(states) if self._can_dispatch else None
-        self._micros.append(_Micro(states, n_real, dst, token))
-        self._m_batches.inc()
-        self._m_fill.observe(n_real)
+        with self.trace.span("sim-flush", cat="sim",
+                             tid=self.trace.current_track()):
+            rows, dst = [], []
+            for cls in PRIORITY_CLASSES:
+                q = self._queues[cls]
+                while q and len(rows) < self.max_batch:
+                    state, ticket, i, _ = q.popleft()
+                    rows.append(state)
+                    dst.append((ticket, i))
+            if not rows:
+                return
+            self._queued -= len(rows)
+            n_real = len(rows)
+            if n_real < self.max_batch:
+                rows.extend([rows[0]] * (self.max_batch - n_real))
+                self._m_partial.inc()
+            states = np.stack(rows)
+            token = (self.backend.dispatch(states) if self._can_dispatch
+                     else None)
+            self._micros.append(_Micro(states, n_real, dst, token))
+            self._m_batches.inc()
+            self._m_fill.observe(n_real)
 
     def _finalize(self, micro: _Micro) -> None:
-        if self._can_dispatch:
-            values, priors = self.backend.finalize(micro.token, micro.states)
-        else:
-            values, priors = self.backend.evaluate(micro.states)
-        for j, (ticket, row) in enumerate(micro.dst):
-            ticket.values[row] = values[j]
-            if priors is not None:
-                if ticket.priors is None:
-                    ticket.priors = np.zeros(
-                        (ticket.n, priors.shape[1]), priors.dtype)
-                ticket.priors[row] = priors[j]
-            ticket.filled += 1
+        with self.trace.span("sim-finalize", cat="sim",
+                             tid=self.trace.current_track(),
+                             rows=micro.n_real):
+            if self._can_dispatch:
+                values, priors = self.backend.finalize(micro.token,
+                                                       micro.states)
+            else:
+                values, priors = self.backend.evaluate(micro.states)
+            for j, (ticket, row) in enumerate(micro.dst):
+                ticket.values[row] = values[j]
+                if priors is not None:
+                    if ticket.priors is None:
+                        ticket.priors = np.zeros(
+                            (ticket.n, priors.shape[1]), priors.dtype)
+                    ticket.priors[row] = priors[j]
+                ticket.filled += 1

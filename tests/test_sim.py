@@ -100,15 +100,16 @@ def test_vectorized_evaluate_matches_rowwise_reference(nn_backend):
     masked-softmax semantics (fixed-width 36-cell reductions)."""
     import jax
 
-    from repro.envs.policy_net import _infer
+    from repro.envs.policy_net import policy_value_forward
 
     states = _gomoku_states(48, seed=1)
     vals, pris = nn_backend.evaluate(states)
 
     values, logits = jax.device_get(
-        _infer(nn_backend.params,
-               np.asarray([st[3:39].reshape(6, 6) * st[0] for st in states],
-                          np.float32)))
+        policy_value_forward(
+            nn_backend.params,
+            np.asarray([st[3:39].reshape(6, 6) * st[0] for st in states],
+                       np.float32)))
     for i, st in enumerate(states):
         term = st[1] != 0
         if term:
