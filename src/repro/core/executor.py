@@ -58,9 +58,10 @@ import numpy as np
 
 from repro.core import intree, ref_sequential as ref
 from repro.core.tree import (
-    NULL, ROW_KEYS, TreeConfig, UCTree, arena_set_slot, arena_slot,
-    init_arena, init_rows, init_tree, pack_rows, pad_rows, read_slot_rows,
-    reset_slot_rows, row_bucket, to_jax, unpack_rows, write_slot_rows,
+    NULL, ROW_KEYS, PackedTree, TreeConfig, UCTree, arena_set_slot,
+    arena_slot, init_arena, init_rows, init_tree, pack_rows, pack_tree,
+    pad_rows, read_slot_rows, reset_slot_rows, row_bucket, to_jax,
+    unpack_rows, unpack_tree, write_slot_rows,
 )
 from repro.obs.trace import NULL_TRACER
 
@@ -213,6 +214,11 @@ def _sel_to_host(sel) -> dict:
 class JaxExecutor:
     """Vmapped jit in-tree operations over G stacked trees.
 
+    The arena lives on the device as one PackedTree (core/tree.py), in the
+    rows the Pallas kernels read, for every variant; the host-side slot
+    access below speaks the logical [R, Fp] / [R] layout and converts with
+    a numpy reshape.
+
     `device` commits the arena to one specific device (multi-device
     serving: core/sharded.py builds one executor per shard).  Every op —
     eager and jit — then follows the committed placement, and the host
@@ -222,7 +228,7 @@ class JaxExecutor:
     """
 
     def __init__(self, cfg: TreeConfig, G: int, variant: str = "faithful",
-                 _trees: Optional[UCTree] = None, device=None):
+                 _trees: Optional[PackedTree] = None, device=None):
         if variant not in ("faithful", "relaxed", "wavefront"):
             raise NotImplementedError(
                 f"JaxExecutor variant {variant!r}: the vmappable jit paths "
@@ -321,8 +327,8 @@ class JaxExecutor:
         self._warm_buckets.add(R)
         g = np.int32(0)
         jax.block_until_ready(read_slot_rows(self.trees, g, R))
-        jax.block_until_ready(write_slot_rows(
-            self.trees, g, pack_rows(init_rows(self.cfg.Fp, R, 1, np))))
+        fresh = pack_rows(self.cfg, init_rows(self.cfg.Fp, R, 1, np), R)
+        jax.block_until_ready(write_slot_rows(self.trees, g, fresh))
         jax.block_until_ready(reset_slot_rows(self.trees, g, np.int32(1), R))
         return R
 
@@ -347,9 +353,9 @@ class JaxExecutor:
     def slot_rows(self, g: int) -> dict:
         """Slot g's first R rows (R covering its size), `size` and
         `root`, copied down in one device_get."""
-        blocks = read_slot_rows(self.trees, np.int32(g),
-                                self._bucket(self.sizes()[g]))
-        return unpack_rows(jax.device_get(blocks))
+        R = self._bucket(self.sizes()[g])
+        blocks = read_slot_rows(self.trees, np.int32(g), R)
+        return unpack_rows(self.cfg, jax.device_get(blocks), R)
 
     def slot_snapshot(self, g: int) -> dict:
         return pad_rows(self.cfg, self.slot_rows(g))
@@ -357,10 +363,11 @@ class JaxExecutor:
     def write_slot(self, g: int, arrays: dict):
         """Write the rows of `arrays` (a bucket of them, or all X, at or
         above the slot's old size), `size` and `root` into slot g in one
-        program."""
-        self._bucket(len(arrays["child"]))
+        program.  Rows from len(arrays) up to the bucket get their initial
+        values, which they hold already."""
+        R = self._bucket(len(arrays["child"]))
         self.trees = write_slot_rows(self.trees, np.int32(g),
-                                     pack_rows(arrays))
+                                     pack_rows(self.cfg, arrays, R))
 
     def block(self):
         """Wait for every queued write to the arena (tracing fences)."""
@@ -373,7 +380,7 @@ class JaxExecutor:
         self.trees = None
 
     # -- compaction (gather active slots into a dense sub-arena) -------
-    def _spawn(self, trees: UCTree, Gc: int) -> "JaxExecutor":
+    def _spawn(self, trees: PackedTree, Gc: int) -> "JaxExecutor":
         # gathered trees inherit the parent's committed placement, so the
         # sub-executor records the same device without a fresh device_put
         return JaxExecutor(self.cfg, Gc, self.variant, _trees=trees,
@@ -396,21 +403,22 @@ class JaxExecutor:
         return CompactionSession(self, slot_idx, Gc, tracer=tracer, tid=tid)
 
     # -- single-tree compat surface (G=1 driver / tests) ---------------
+    # Trees cross in the logical layout, as numpy on the way out.
     def init(self, root_num_actions: int) -> UCTree:
-        return init_tree(self.cfg, root_num_actions)
+        return init_tree(self.cfg, root_num_actions, xp=np)
 
     def get_tree(self, g: int = 0) -> UCTree:
-        return arena_slot(self.trees, g)
+        return unpack_tree(arena_slot(self.trees, g))
 
     def set_tree(self, tree: UCTree, g: int = 0):
-        self.trees = arena_set_slot(self.trees, g, to_jax(tree))
+        self.trees = arena_set_slot(self.trees, g, to_jax(pack_tree(tree)))
 
     def snapshot(self, tree) -> dict:
         return {k: np.asarray(v) for k, v in dataclasses.asdict(
             jax.device_get(tree)).items()}
 
     def best_action(self, tree) -> int:
-        return int(intree.best_root_action(tree))
+        return int(intree.best_root_action(to_jax(pack_tree(tree))))
 
 
 class PallasExecutor(JaxExecutor):
@@ -425,7 +433,7 @@ class PallasExecutor(JaxExecutor):
     """
 
     def __init__(self, cfg: TreeConfig, G: int,
-                 _trees: Optional[UCTree] = None, device=None):
+                 _trees: Optional[PackedTree] = None, device=None):
         super().__init__(cfg, G, "faithful", _trees=_trees, device=device)
         self._fused_variant = "pallas"
         from repro.kernels import ops as kops  # lazy: keeps core import-light
@@ -446,7 +454,7 @@ class PallasExecutor(JaxExecutor):
             jnp.asarray(sim_nodes), jnp.asarray(values_fx), alternating)
         # no fence — same async-dispatch contract as JaxExecutor.backup
 
-    def _spawn(self, trees: UCTree, Gc: int) -> "PallasExecutor":
+    def _spawn(self, trees: PackedTree, Gc: int) -> "PallasExecutor":
         return PallasExecutor(self.cfg, Gc, _trees=trees, device=self.device)
 
 

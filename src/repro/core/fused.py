@@ -61,7 +61,7 @@ import numpy as np
 
 from repro.core import fixedpoint as fx
 from repro.core import intree
-from repro.core.tree import NULL, TreeConfig, UCTree
+from repro.core.tree import NULL, PackedTree, TreeConfig
 
 # escape reasons surfaced to the pool/scheduler accounting
 ESC_RAN_K = 0    # ran all K supersteps, no boundary hit
@@ -109,7 +109,7 @@ def _zero_sel(Ge: int, p: int, D: int) -> intree.SelectionResult:
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6))
 def _fused_program(cfg: TreeConfig, variant: str, p: int, K: int,
                    env, sim, alternating: bool,
-                   arena: UCTree, states, active, budget_left):
+                   arena: PackedTree, states, active, budget_left):
     """The compiled dispatch.  Static args make the cache key; arena,
     the ST buffer, the active mask [Ge] and per-slot remaining budgets
     [Ge] are traced."""
@@ -224,7 +224,7 @@ class PendingDispatch:
     new_nodes: Any       # [Ge, p, Fp] device id block
 
 
-def submit_supersteps(cfg: TreeConfig, variant: str, trees: UCTree,
+def submit_supersteps(cfg: TreeConfig, variant: str, trees: PackedTree,
                       active, p: int, K: int, env, sim, states,
                       budget_left, alternating: bool):
     """Queue up to K fused supersteps WITHOUT any host read.  Returns

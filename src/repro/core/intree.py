@@ -39,7 +39,10 @@ import jax.numpy as jnp
 
 from repro.core import fixedpoint as fx
 from repro.core import scoring
-from repro.core.tree import NULL, TreeConfig, UCTree, where_trees
+from repro.core.tree import (
+    NULL, PackedTree, TreeConfig, edge_add, edge_get, edge_row, edge_set,
+    node_add, node_get, node_set, where_trees,
+)
 
 
 @jax.tree_util.register_dataclass
@@ -54,24 +57,40 @@ class SelectionResult:
     insert_base: Any    # [p] i32: first node id this worker will insert
 
 
-def _scores_at(cfg: TreeConfig, tree: UCTree, node, edge_VL, node_O):
+def _scores_at(cfg: TreeConfig, tree: PackedTree, node, edge_VL, node_O):
+    """Scores of the edges of `node` ([...] ids -> [..., Fp])."""
+    node_N = node_get(tree.node_N, node)[..., None]
+    node_O = node_get(node_O, node)[..., None]
+    ns = scoring.log_index(cfg, node_N, node_O, xp=jnp)
     return scoring.edge_scores_fx(
         cfg,
-        child=tree.child[node],
-        edge_N=tree.edge_N[node],
-        edge_W=tree.edge_W[node],
-        edge_VL=edge_VL[node],
-        edge_P=tree.edge_P[node],
-        node_N=tree.node_N[node][None],
-        node_O=node_O[node][None],
-        num_actions=tree.num_actions[node][None],
-        log_table=tree.log_table,
+        child=edge_row(tree, tree.child, node),
+        edge_N=edge_row(tree, tree.edge_N, node),
+        edge_W=edge_row(tree, tree.edge_W, node),
+        edge_VL=edge_row(tree, edge_VL, node),
+        edge_P=edge_row(tree, tree.edge_P, node),
+        node_N=node_N,
+        node_O=node_O,
+        num_actions=node_get(tree.num_actions, node)[..., None],
+        log_ns=node_get(tree.log_table, ns),
+        xp=jnp,
+    )
+
+
+def _is_leaf_at(cfg: TreeConfig, tree: PackedTree, node, depth):
+    return scoring.is_leaf(
+        cfg,
+        num_expanded=node_get(tree.num_expanded, node),
+        num_actions=node_get(tree.num_actions, node),
+        terminal=node_get(tree.terminal, node),
+        depth=depth,
         xp=jnp,
     )
 
 
 @functools.partial(jax.jit, static_argnums=(0, 2, 3))
-def select_batch(cfg: TreeConfig, tree: UCTree, p: int, relaxed: bool = False):
+def select_batch(cfg: TreeConfig, tree: PackedTree, p: int,
+                 relaxed: bool = False):
     """Selection for p workers.  Returns (tree', SelectionResult)."""
     D = cfg.D
     i32 = jnp.int32
@@ -79,30 +98,23 @@ def select_batch(cfg: TreeConfig, tree: UCTree, p: int, relaxed: bool = False):
     def descend(j, carry):
         edge_VL, node_O, pn, pa, depths, leaves = carry
         if not relaxed:
-            node_O = node_O.at[tree.root].add(1)
+            node_O = node_add(tree, node_O, tree.root, 1)
 
         def level(d, st):
             node, depth, edge_VL, node_O, pn, pa = st
-            leaf = scoring.is_leaf(
-                cfg,
-                num_expanded=tree.num_expanded[node],
-                num_actions=tree.num_actions[node],
-                terminal=tree.terminal[node],
-                depth=depth,
-                xp=jnp,
-            )
+            leaf = _is_leaf_at(cfg, tree, node, depth)
             active = (~leaf) & (d == depth)
             s = _scores_at(cfg, tree, node, edge_VL, node_O)
             a = scoring.argmax_first(s, xp=jnp)
             inc = jnp.where(active, i32(1), i32(0))
             if not relaxed:
-                edge_VL = edge_VL.at[node, a].add(inc)
-            nxt = tree.child[node, a]
+                edge_VL = edge_add(tree, edge_VL, node, a, inc)
+            nxt = edge_get(tree, tree.child, node, a)
             pn = pn.at[j, d].set(jnp.where(active, node, pn[j, d]))
             pa = pa.at[j, d].set(jnp.where(active, a, pa[j, d]))
             node = jnp.where(active, nxt, node)
             if not relaxed:
-                node_O = node_O.at[node].add(inc)
+                node_O = node_add(tree, node_O, node, inc)
             depth = depth + inc
             return node, depth, edge_VL, node_O, pn, pa
 
@@ -124,14 +136,19 @@ def select_batch(cfg: TreeConfig, tree: UCTree, p: int, relaxed: bool = False):
     if relaxed:
         # Beyond-paper: one-shot VL/O application after all choices; scores
         # above read only the pre-superstep statistics (no serial chain).
-        X = tree.X
-        idx_n = jnp.where(pn != NULL, pn, X)
-        edge_VL = edge_VL.at[idx_n, pa].add(1, mode="drop")
-        node_O = node_O.at[idx_n].add(1, mode="drop")
-        node_O = node_O.at[leaves].add(1)
+        edge_VL, node_O = _apply_virtual_loss(tree, edge_VL, node_O, pn, pa,
+                                              leaves)
 
     tree = dataclasses.replace(tree, edge_VL=edge_VL, node_O=node_O)
     return _assign_expansions(cfg, tree, pn, pa, depths, leaves, p)
+
+
+def _apply_virtual_loss(tree: PackedTree, edge_VL, node_O, pn, pa, leaves):
+    """Virtual loss and in-flight counts of whole paths, added at once."""
+    idx_n = jnp.where(pn != NULL, pn, tree.X)
+    edge_VL = edge_add(tree, edge_VL, idx_n, pa, 1)
+    node_O = node_add(tree, node_O, idx_n, 1)
+    return edge_VL, node_add(tree, node_O, leaves, 1)
 
 
 def _segment_rank(keys, p):
@@ -147,7 +164,7 @@ def _segment_rank(keys, p):
 
 
 @functools.partial(jax.jit, static_argnums=(0, 2))
-def select_batch_wavefront(cfg: TreeConfig, tree: UCTree, p: int):
+def select_batch_wavefront(cfg: TreeConfig, tree: PackedTree, p: int):
     """Beyond-paper selection: level-synchronous wavefront with rank-based
     repulsion.
 
@@ -162,34 +179,15 @@ def select_batch_wavefront(cfg: TreeConfig, tree: UCTree, p: int):
     VL-based).  Diversity impact vs the faithful path is measured in
     benchmarks (bench_diversity).
     """
-    D, Fp, X = cfg.D, cfg.Fp, tree.X
+    D, X = cfg.D, tree.X
     i32 = jnp.int32
     w = jnp.arange(p, dtype=i32)
 
     def level(d, st):
         nodes, depth, pn, pa = st
-        leaf = scoring.is_leaf(
-            cfg,
-            num_expanded=tree.num_expanded[nodes],
-            num_actions=tree.num_actions[nodes],
-            terminal=tree.terminal[nodes],
-            depth=depth,
-            xp=jnp,
-        )
+        leaf = _is_leaf_at(cfg, tree, nodes, depth)
         active = (~leaf) & (depth == d)
-        s = scoring.edge_scores_fx(
-            cfg,
-            child=tree.child[nodes],
-            edge_N=tree.edge_N[nodes],
-            edge_W=tree.edge_W[nodes],
-            edge_VL=tree.edge_VL[nodes],
-            edge_P=tree.edge_P[nodes],
-            node_N=tree.node_N[nodes][:, None],
-            node_O=tree.node_O[nodes][:, None],
-            num_actions=tree.num_actions[nodes][:, None],
-            log_table=tree.log_table,
-            xp=jnp,
-        )                                                   # [p, Fp]
+        s = _scores_at(cfg, tree, nodes, tree.edge_VL, tree.node_O)  # [p, Fp]
         order = jnp.argsort(-s, axis=-1, stable=True)       # best-first, ties by lane
         n_valid = jnp.maximum(jnp.sum(s > fx.FX_NEG_INF, axis=-1), 1).astype(i32)
         rank = _segment_rank(jnp.where(active, nodes, X + w), p)
@@ -197,7 +195,7 @@ def select_batch_wavefront(cfg: TreeConfig, tree: UCTree, p: int):
             order, (rank % n_valid)[:, None], axis=-1)[:, 0].astype(i32)
         pn = pn.at[w, d].set(jnp.where(active, nodes, pn[:, d]))
         pa = pa.at[w, d].set(jnp.where(active, a, pa[:, d]))
-        nodes = jnp.where(active, tree.child[nodes, a], nodes)
+        nodes = jnp.where(active, edge_get(tree, tree.child, nodes, a), nodes)
         depth = depth + jnp.where(active, i32(1), i32(0))
         return nodes, depth, pn, pa
 
@@ -207,9 +205,8 @@ def select_batch_wavefront(cfg: TreeConfig, tree: UCTree, p: int):
         0, D, level, (jnp.broadcast_to(tree.root, (p,)), jnp.zeros(p, i32), pn, pa)
     )
     leaves = nodes
-    idx_n = jnp.where(pn != NULL, pn, X)
-    edge_VL = tree.edge_VL.at[idx_n, pa].add(1, mode="drop")
-    node_O = tree.node_O.at[idx_n].add(1, mode="drop").at[leaves].add(1)
+    edge_VL, node_O = _apply_virtual_loss(tree, tree.edge_VL, tree.node_O,
+                                          pn, pa, leaves)
     tree = dataclasses.replace(tree, edge_VL=edge_VL, node_O=node_O)
     return _assign_expansions(cfg, tree, pn, pa, depths, leaves, p)
 
@@ -222,31 +219,34 @@ def _assign_expansions(cfg, tree, pn, pa, depths, leaves, p):
     def assign(j, carry):
         pending, claimed, budget, ea, ni = carry
         leaf = leaves[j]
-        can = (tree.terminal[leaf] == 0) & (depths[j] < cfg.D)
+        can = (node_get(tree.terminal, leaf) == 0) & (depths[j] < cfg.D)
+        n_act = node_get(tree.num_actions, leaf)
+        n_exp = node_get(tree.num_expanded, leaf)
         if cfg.expand_all:
-            k = tree.num_actions[leaf]
             ok = (
                 can
-                & (claimed[leaf] == 0)
-                & (tree.num_expanded[leaf] == 0)
-                & (k > 0)
-                & (budget >= k)
+                & (node_get(claimed, leaf) == 0)
+                & (n_exp == 0)
+                & (n_act > 0)
+                & (budget >= n_act)
             )
             ea = ea.at[j].set(jnp.where(ok, i32(-2), i32(NULL)))
-            ni = ni.at[j].set(jnp.where(ok, k, i32(0)))
-            claimed = claimed.at[leaf].max(jnp.where(ok, i32(1), i32(0)))
-            budget = budget - jnp.where(ok, k, i32(0))
+            ni = ni.at[j].set(jnp.where(ok, n_act, i32(0)))
+            claimed = node_add(tree, claimed, leaf,
+                               jnp.where(ok, i32(1), i32(0)))
+            budget = budget - jnp.where(ok, n_act, i32(0))
         else:
-            a = tree.num_expanded[leaf] + pending[leaf]
-            ok = can & (a < tree.num_actions[leaf]) & (budget >= 1)
+            a = n_exp + node_get(pending, leaf)
+            ok = can & (a < n_act) & (budget >= 1)
             ea = ea.at[j].set(jnp.where(ok, a, i32(NULL)))
             ni = ni.at[j].set(jnp.where(ok, i32(1), i32(0)))
-            pending = pending.at[leaf].add(jnp.where(ok, i32(1), i32(0)))
+            pending = node_add(tree, pending, leaf,
+                               jnp.where(ok, i32(1), i32(0)))
             budget = budget - jnp.where(ok, i32(1), i32(0))
         return pending, claimed, budget, ea, ni
 
-    pending = jnp.zeros(tree.X, dtype=i32)
-    claimed = jnp.zeros(tree.X, dtype=i32)
+    pending = jnp.zeros_like(tree.num_expanded)   # per-node counts, packed
+    claimed = jnp.zeros_like(tree.num_expanded)
     ea = jnp.full(p, NULL, dtype=i32)
     ni = jnp.zeros(p, dtype=i32)
     budget0 = jnp.asarray(cfg.X, i32) - tree.size
@@ -259,7 +259,7 @@ def _assign_expansions(cfg, tree, pn, pa, depths, leaves, p):
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
-def insert_batch(cfg: TreeConfig, tree: UCTree, sel: SelectionResult):
+def insert_batch(cfg: TreeConfig, tree: PackedTree, sel: SelectionResult):
     """Node Insertion for all workers at once (vectorized scatter).
 
     Returns (tree', new_nodes[p, Fp] NULL-padded).  Distinctness of target
@@ -279,14 +279,14 @@ def insert_batch(cfg: TreeConfig, tree: UCTree, sel: SelectionResult):
     leaf = jnp.broadcast_to(sel.leaves[:, None], (p, Fp))
 
     li = jnp.where(valid, leaf, X)
-    ai = jnp.where(valid, act, Fp)
     ci = jnp.where(valid, nid, X)
-    child = tree.child.at[li, ai].set(jnp.where(valid, nid, NULL), mode="drop")
-    node_depth = tree.node_depth.at[ci].set(
-        tree.node_depth[sel.leaves][:, None] + 1, mode="drop")
-    num_actions = tree.num_actions.at[ci].set(i32(cfg.F), mode="drop")
-    num_expanded = tree.num_expanded.at[jnp.where(valid, leaf, X)].add(
-        jnp.where(valid, i32(1), i32(0)), mode="drop")
+    child = edge_set(tree, tree.child, li, act, jnp.where(valid, nid, NULL))
+    leaf_depth = node_get(tree.node_depth, sel.leaves)[:, None]   # [p, 1]
+    node_depth = node_set(tree, tree.node_depth, ci,
+                          jnp.broadcast_to(leaf_depth + 1, (p, Fp)))
+    num_actions = node_set(tree, tree.num_actions, ci, i32(cfg.F))
+    num_expanded = node_add(tree, tree.num_expanded, li,
+                            jnp.where(valid, i32(1), i32(0)))
     size = tree.size + jnp.sum(sel.n_insert, dtype=i32)
     new_nodes = jnp.where(valid, nid, NULL)
     tree = dataclasses.replace(
@@ -297,28 +297,26 @@ def insert_batch(cfg: TreeConfig, tree: UCTree, sel: SelectionResult):
 
 @jax.jit
 def finalize_expansion_batch(
-    tree: UCTree,
+    tree: PackedTree,
     nodes,          # [k] i32 (NULL-padded ok)
     num_actions,    # [k] i32
     terminal,       # [k] i32
     prior_parent=None,   # [k2] i32 parent ids (NULL-padded ok)
     priors_fx=None,      # [k2, Fp] i32
 ):
-    X = tree.X
-    idx = jnp.where(nodes == NULL, X, nodes)
-    na = tree.num_actions.at[idx].set(num_actions, mode="drop")
-    tm = tree.terminal.at[idx].set(terminal, mode="drop")
+    na = node_set(tree, tree.num_actions, nodes, num_actions)
+    tm = node_set(tree, tree.terminal, nodes, terminal)
     ep = tree.edge_P
     if priors_fx is not None:
-        pidx = jnp.where(prior_parent == NULL, X, prior_parent)
-        ep = ep.at[pidx].set(priors_fx, mode="drop")
+        lane = jnp.arange(tree.Fp, dtype=jnp.int32)
+        ep = edge_set(tree, ep, prior_parent[:, None], lane, priors_fx)
     return dataclasses.replace(tree, num_actions=na, terminal=tm, edge_P=ep)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 5, 6))
 def backup_batch(
     cfg: TreeConfig,
-    tree: UCTree,
+    tree: PackedTree,
     sel: SelectionResult,
     sim_nodes,      # [p] i32
     values_fx,      # [p] i32 Qm.16
@@ -359,27 +357,28 @@ def backup_batch(
     ninc = rinc * jnp.where(alive, i32(1), i32(0))[:, None]   # accumulation
     winc = ninc * sign * values_fx[:, None]
     li = jnp.where(on_path, sel.path_nodes, X)
-    ai = jnp.where(on_path, sel.path_actions, tree.Fp)
+    ai = sel.path_actions
 
-    edge_N = tree.edge_N.at[li, ai].add(ninc, mode="drop")
-    edge_W = tree.edge_W.at[li, ai].add(winc, mode="drop")
-    edge_VL = tree.edge_VL.at[li, ai].add(-rinc, mode="drop")
-    node_N = tree.node_N.at[li].add(ninc, mode="drop")
-    node_O = tree.node_O.at[li].add(-rinc, mode="drop")
-    node_N = node_N.at[sel.leaves].add(jnp.where(alive, i32(1), i32(0)))
-    node_O = node_O.at[sel.leaves].add(-1)
+    edge_N = edge_add(tree, tree.edge_N, li, ai, ninc)
+    edge_W = edge_add(tree, tree.edge_W, li, ai, winc)
+    edge_VL = edge_add(tree, tree.edge_VL, li, ai, -rinc)
+    node_N = node_add(tree, tree.node_N, li, ninc)
+    node_O = node_add(tree, tree.node_O, li, -rinc)
+    node_N = node_add(tree, node_N, sel.leaves,
+                      jnp.where(alive, i32(1), i32(0)))
+    node_O = node_add(tree, node_O, sel.leaves, i32(-1))
 
     # Expansion edges (single-expand mode): seed the sim node's in-edge.
     live_exp = expanded & alive
     e_leaf = jnp.where(live_exp, sel.leaves, X)
-    e_act = jnp.where(live_exp, sel.expand_action, tree.Fp)
     e_sign = jnp.where(
         jnp.asarray(alternating_signs) & ((sim_depth - sel.depths) % 2 == 1),
         i32(-1), i32(1))
     e_inc = jnp.where(live_exp, i32(1), i32(0))
-    edge_N = edge_N.at[e_leaf, e_act].add(e_inc, mode="drop")
-    edge_W = edge_W.at[e_leaf, e_act].add(e_inc * e_sign * values_fx, mode="drop")
-    node_N = node_N.at[jnp.where(live_exp, sim_nodes, X)].add(1, mode="drop")
+    edge_N = edge_add(tree, edge_N, e_leaf, sel.expand_action, e_inc)
+    edge_W = edge_add(tree, edge_W, e_leaf, sel.expand_action,
+                      e_inc * e_sign * values_fx)
+    node_N = node_add(tree, node_N, jnp.where(live_exp, sim_nodes, X), i32(1))
 
     return dataclasses.replace(
         tree, edge_N=edge_N, edge_W=edge_W, edge_VL=edge_VL,
@@ -387,12 +386,12 @@ def backup_batch(
 
 
 @jax.jit
-def best_root_action(tree: UCTree):
+def best_root_action(tree: PackedTree):
     """Robust-child action choice at the MCTS step boundary."""
-    Fp = tree.Fp
-    lane = jnp.arange(Fp, dtype=jnp.int32)
-    n = tree.edge_N[tree.root]
-    ok = (lane < tree.num_actions[tree.root]) & (tree.child[tree.root] != NULL)
+    lane = jnp.arange(tree.Fp, dtype=jnp.int32)
+    n = edge_row(tree, tree.edge_N, tree.root)
+    ok = ((lane < node_get(tree.num_actions, tree.root))
+          & (edge_row(tree, tree.child, tree.root) != NULL))
     return jnp.argmax(jnp.where(ok, n, -1)).astype(jnp.int32)
 
 
@@ -400,7 +399,7 @@ def best_root_action(tree: UCTree):
 # Arena entry points (service layer): every op vmapped over G stacked trees
 # --------------------------------------------------------------------------
 #
-# The arena (tree.init_arena / stack_trees) carries G independent searches
+# The arena (tree.init_arena, one PackedTree) carries G independent searches
 # in one pytree; these wrappers run the single-tree ops above on every slot
 # in ONE device program.  `active` is a [G] bool mask: the op still executes
 # on idle slots (a uniform program, no ragged dispatch) but where_trees
@@ -415,7 +414,7 @@ def best_root_action(tree: UCTree):
 # [G]-grid launch instead of vmap) behind the same executor contract.
 
 @functools.partial(jax.jit, static_argnums=(0, 3, 4))
-def select_arena(cfg: TreeConfig, arena: UCTree, active, p: int,
+def select_arena(cfg: TreeConfig, arena: PackedTree, active, p: int,
                  variant: str = "faithful"):
     """Selection for p workers on every slot.  Returns (arena', sel[G,...])."""
     if variant == "wavefront":
@@ -427,14 +426,14 @@ def select_arena(cfg: TreeConfig, arena: UCTree, active, p: int,
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
-def insert_arena(cfg: TreeConfig, arena: UCTree, active, sel):
+def insert_arena(cfg: TreeConfig, arena: PackedTree, active, sel):
     """Node Insertion on every slot.  Returns (arena', new_nodes[G, p, Fp])."""
     new, nodes = jax.vmap(lambda t, s: insert_batch(cfg, t, s))(arena, sel)
     return where_trees(active, new, arena), nodes
 
 
 @jax.jit
-def finalize_arena(arena: UCTree, nodes, num_actions, terminal,
+def finalize_arena(arena: PackedTree, nodes, num_actions, terminal,
                    prior_parent, priors_fx):
     """finalize_expansion_batch per slot.  All inputs carry a leading [G]
     axis; idle/short slots are NULL-padded rows (finalize is NULL-safe), so
@@ -444,7 +443,7 @@ def finalize_arena(arena: UCTree, nodes, num_actions, terminal,
 
 
 @functools.partial(jax.jit, static_argnums=(0, 6, 7))
-def backup_arena(cfg: TreeConfig, arena: UCTree, active, sel, sim_nodes,
+def backup_arena(cfg: TreeConfig, arena: PackedTree, active, sel, sim_nodes,
                  values_fx, alternating_signs: bool = False,
                  with_mask: bool = False, dropped=None):
     """BackUp on every slot ([G, p] sim nodes / values).  With
@@ -463,6 +462,6 @@ def backup_arena(cfg: TreeConfig, arena: UCTree, active, sel, sim_nodes,
 
 
 @jax.jit
-def best_root_action_arena(arena: UCTree):
+def best_root_action_arena(arena: PackedTree):
     """Robust-child action for every slot.  Returns [G] i32."""
     return jax.vmap(best_root_action)(arena)

@@ -273,7 +273,7 @@ class TreeParallelMCTS:
         return a, reward, term
 
     def _size(self):
-        return self.tree.size
+        return self.exec.sizes()[0]
 
     def close(self):
         """Release expansion-engine resources (process pool, if any)."""

@@ -111,6 +111,13 @@ def sqrt_rn(x, xp=np):
     return nearest_root(x, xp.sqrt(x), xp)
 
 
+def log_index(cfg: TreeConfig, node_N, node_O, xp=np):
+    """A node's visit count Ns as the scores read it (in-flight visits
+    included under WU-UCT), clamped to the log table (tree.py)."""
+    ns = node_N + node_O if cfg.vl_mode == "wu" else node_N
+    return xp.minimum(ns, xp.int32(2 * cfg.X + 3))
+
+
 def edge_scores_fx(
     cfg: TreeConfig,
     *,
@@ -136,13 +143,8 @@ def edge_scores_fx(
         lane = xp.arange(Fp, dtype=i32)
     valid = (lane < num_actions) & (child != NULL)
 
-    if cfg.vl_mode == "wu":
-        ne = edge_N + edge_VL                    # N̄ = N + O (in-flight)
-        ns = node_N + node_O
-    else:
-        ne = edge_N
-        ns = node_N
-    ns = xp.minimum(ns, i32(2 * cfg.X + 3))      # log-table bound (tree.py)
+    ne = edge_N + edge_VL if cfg.vl_mode == "wu" else edge_N  # N̄ = N + O
+    ns = log_index(cfg, node_N, node_O, xp)
 
     ne_safe = xp.maximum(ne, i32(1)).astype(f32)
     if log_ns is None:

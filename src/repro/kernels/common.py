@@ -4,8 +4,10 @@ Memory layout (TPU adaptation of the paper's SRAM banking, §IV-B):
 
 The paper stores the UCT as a compact adjacency list in per-level SRAM
 banks sized for single-cycle access.  On TPU the analogue is VMEM
-residency with VPU-aligned rows: every ``[X, Fp]`` edge-statistic array is
-packed into ``[X*Fp/128, 128]`` int32 so that
+residency with VPU-aligned rows: the arena stores every edge-statistic
+array as ``[X*Fp/128, 128]`` int32 rows, rounded up to whole (8, 128)
+tiles (core/tree.PackedTree), and the kernels block-map those rows into
+VMEM as they are, so that
 
   * a node's Fp-edge block lives in ONE 128-lane VMEM row (Fp is a power
     of two <= 128, so blocks never straddle rows) — one vector load plays
@@ -16,8 +18,8 @@ packed into ``[X*Fp/128, 128]`` int32 so that
   * updates are full-row read-modify-writes (no sub-lane dynamic stores,
     which Mosaic lowers poorly).
 
-Node-indexed ``[X]`` arrays are packed into ``[ceil(X/128), 128]`` rows and
-accessed with the same row RMW discipline.
+Node-indexed arrays and the ln table are stored as ``[ceil(n/128), 128]``
+rows and accessed with the same row RMW discipline.
 """
 
 from __future__ import annotations
@@ -26,64 +28,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANES = 128
+from repro.core.tree import LANES  # noqa: F401  (the kernels' row width)
 
-
-def padded_x(x: int, fp: int) -> int:
-    """Smallest X' >= x with X'*Fp a multiple of 128."""
-    step = max(1, LANES // fp)
-    return ((x + step - 1) // step) * step
-
-
-def pack_edges(arr, fp: int):
-    """[X, Fp] -> [Xp*Fp/128, 128] (row-aligned node blocks)."""
-    x = arr.shape[0]
-    xp_ = padded_x(x, fp)
-    if xp_ != x:
-        arr = jnp.concatenate(
-            [arr, jnp.zeros((xp_ - x, fp), arr.dtype)], axis=0)
-    return arr.reshape(xp_ * fp // LANES, LANES)
-
-
-def unpack_edges(packed, x: int, fp: int):
-    return packed.reshape(-1, fp)[:x]
-
-
-def pack_nodes(arr):
-    """[X] -> [ceil(X/128), 128]."""
-    x = arr.shape[0]
-    xp_ = ((x + LANES - 1) // LANES) * LANES
-    if xp_ != x:
-        arr = jnp.concatenate([arr, jnp.zeros((xp_ - x,), arr.dtype)])
-    return arr.reshape(xp_ // LANES, LANES)
-
-
-def unpack_nodes(packed, x: int):
-    return packed.reshape(-1)[:x]
-
-
-# ---- arena packing: a leading [G] slot axis over the same row layout ----
-#
-# The arena kernels block-map one slot per grid program, so the packed
-# layout just gains a leading G axis: [G, X, Fp] -> [G, X*Fp/128, 128].
-# vmap of the single-tree helpers keeps the two layouts one definition.
-
-def pack_edges_arena(arr, fp: int):
-    """[G, X, Fp] -> [G, Xp*Fp/128, 128]."""
-    return jax.vmap(lambda a: pack_edges(a, fp))(arr)
-
-
-def unpack_edges_arena(packed, x: int, fp: int):
-    return jax.vmap(lambda a: unpack_edges(a, x, fp))(packed)
-
-
-def pack_nodes_arena(arr):
-    """[G, X] -> [G, ceil(X/128), 128]."""
-    return jax.vmap(pack_nodes)(arr)
-
-
-def unpack_nodes_arena(packed, x: int):
-    return jax.vmap(lambda a: unpack_nodes(a, x))(packed)
+# Each grid program block-maps its slot's whole UCT into VMEM (ROADMAP S5
+# would bound the copy by the live rows).  Mosaic's default scoped limit,
+# 16 MiB, holds one Pong slot's blocks but not four double-buffered, nor
+# one Gomoku slot's, once the arena reaches the kernels as it is stored in
+# HBM; a TPU v5e has 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 100 << 20
 
 
 # ---- in-kernel access helpers (all row-granular) -------------------------

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import intree
-from repro.core.tree import TreeConfig, UCTree
+from repro.core.tree import PackedTree, TreeConfig
 from repro.kernels import uct_backup, uct_select
 
 
@@ -43,7 +43,7 @@ def _per_platform(kernel, cfg: TreeConfig, *args, **static):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "p"))
-def select_arena(cfg: TreeConfig, arena: UCTree, active, p: int):
+def select_arena(cfg: TreeConfig, arena: PackedTree, active, p: int):
     """Arena Selection; mirrors intree.select_arena.  Returns
     (arena', sel[G, ...]).  The kernel freezes inactive slots itself, so
     the returned arena needs no mask post-select; their sel rows are dead
@@ -59,7 +59,7 @@ def select_arena(cfg: TreeConfig, arena: UCTree, active, p: int):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "alternating_signs"))
-def backup_arena(cfg: TreeConfig, arena: UCTree, active, sel, sim_nodes,
+def backup_arena(cfg: TreeConfig, arena: PackedTree, active, sel, sim_nodes,
                  values_fx, alternating_signs: bool = False):
     """Arena BackUp; mirrors intree.backup_arena (fault-free path)."""
     p = sel.leaves.shape[1]

@@ -8,7 +8,8 @@ selection kernel, and every update is an exact Qm.16 integer add performed
 as a full-row VMEM read-modify-write.
 
 Arena-native like the selection kernel: a ``[G]`` grid maps one program to
-each tree slot (its packed statistic arrays block-mapped into VMEM, its
+each tree slot (its statistic arrays block-mapped into VMEM in the packed
+rows the arena stores, its
 scalars — here just the active flag — scalar-prefetched in SMEM), so all
 G trees back up in one launch and an inactive slot's program is a no-op
 pass-through.  Single-tree backup is the G=1 case.
@@ -28,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.tree import NULL, TreeConfig
+from repro.core.tree import NULL, PackedTree, TreeConfig
 from repro.kernels import common as cm
 from repro.kernels.uct_select import META_ACTIVE, META_WORDS
 
@@ -138,23 +139,18 @@ def _backup_kernel(
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "p", "alternating", "interpret"))
-def backup_arena(cfg: TreeConfig, arena, active, pn, pa, depths, leaves,
-                 expand_action, sim_nodes, values_fx, p: int,
+def backup_arena(cfg: TreeConfig, arena: PackedTree, active, pn, pa, depths,
+                 leaves, expand_action, sim_nodes, values_fx, p: int,
                  alternating: bool, interpret: bool):
     """Backup kernel over a G-slot arena.  All per-worker inputs carry a
     leading [G] axis ([G, p, D] paths, [G, p] scalars); `active` is a [G]
-    mask.  Returns updated (edge_N, edge_W, edge_VL, node_N, node_O) in
-    logical shapes [G, X, Fp] / [G, X]; inactive slots are bit-identical.
+    mask.  Returns updated (edge_N, edge_W, edge_VL, node_N, node_O),
+    packed as the arena stores them and updated in place; inactive slots
+    are bit-identical.
     """
-    Fp = cfg.Fp
-    G, X = arena.child.shape[0], arena.child.shape[1]
-    en_p = cm.pack_edges_arena(arena.edge_N, Fp)
-    ew_p = cm.pack_edges_arena(arena.edge_W, Fp)
-    evl_p = cm.pack_edges_arena(arena.edge_VL, Fp)
-    nn_p = cm.pack_nodes_arena(arena.node_N)
-    no_p = cm.pack_nodes_arena(arena.node_O)
-    er, nr = en_p.shape[1], nn_p.shape[1]
     D = cfg.D
+    G, er, _ = arena.edge_N.shape
+    nr = arena.node_N.shape[1]
     meta = jnp.zeros((G, META_WORDS), jnp.int32)
     meta = meta.at[:, META_ACTIVE].set(jnp.asarray(active, jnp.int32))
 
@@ -181,23 +177,19 @@ def backup_arena(cfg: TreeConfig, arena, active, pn, pa, depths, leaves,
         ],
     )
     # input indices count the scalar-prefetch operand (meta = 0)
-    en2, ew2, evl2, nn2, no2 = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         input_output_aliases={8: 0, 9: 1, 10: 2, 11: 3, 12: 4},
         name="backup_arena",   # the op name traces and readers match
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=cm.VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(
         meta, pn, pa, depths.reshape(G, 1, p), leaves.reshape(G, 1, p),
         expand_action.reshape(G, 1, p), sim_nodes.reshape(G, 1, p),
         values_fx.reshape(G, 1, p),
-        en_p, ew_p, evl_p, nn_p, no_p,
-    )
-    return (
-        cm.unpack_edges_arena(en2, X, Fp),
-        cm.unpack_edges_arena(ew2, X, Fp),
-        cm.unpack_edges_arena(evl2, X, Fp),
-        cm.unpack_nodes_arena(nn2, X),
-        cm.unpack_nodes_arena(no2, X),
+        arena.edge_N, arena.edge_W, arena.edge_VL, arena.node_N,
+        arena.node_O,
     )

@@ -18,7 +18,8 @@ ordering is preserved exactly (worker k sees the virtual loss of workers
 kernel shares the scoring spec of repro.core.scoring verbatim.
 
 Arena-native: the kernel runs on a ``[G]`` grid — one program per tree
-slot, that slot's packed UCT arrays block-mapped into VMEM — so G
+slot, that slot's UCT arrays block-mapped into VMEM in the packed rows
+the arena stores (core/tree.PackedTree, nothing relaid out) — so G
 independent searches (the service layer's arena) cost ONE kernel launch.
 Per-slot scalars (root id, tree size, active flag) ride in an SMEM
 scalar-prefetch operand; an inactive slot's program is a no-op (the
@@ -40,7 +41,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import scoring
-from repro.core.tree import NULL, TreeConfig
+from repro.core.tree import NULL, PackedTree, TreeConfig
 from repro.kernels import common as cm
 
 LANES = cm.LANES
@@ -118,9 +119,7 @@ def _select_kernel(
 
             n_n = cm.sload(node_n_ref, node)
             n_o = cm.sload(node_o_ref, node)
-            ns = n_n + n_o if cfg.vl_mode == "wu" else n_n
-            ns = jnp.minimum(ns, i32(2 * cfg.X + 3))
-            log_ns = cm.sload(log_ref, ns)
+            log_ns = cm.sload(log_ref, scoring.log_index(cfg, n_n, n_o, jnp))
 
             scores = scoring.edge_scores_fx(
                 cfg,
@@ -175,34 +174,25 @@ def _select_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "p", "interpret"))
-def select_arena(cfg: TreeConfig, arena, active, p: int, interpret: bool):
+def select_arena(cfg: TreeConfig, arena: PackedTree, active, p: int,
+                 interpret: bool):
     """Selection kernel over a G-slot arena (one grid program per slot).
 
-    `arena` is a UCTree whose leaves carry a leading [G] axis; `active` is
-    a [G] mask (bool or i32).  Returns (edge_VL', node_O', path_nodes,
-    path_actions, depths, leaves) with logical (unpacked) shapes
-    [G, X, Fp] / [G, X] / [G, p, D] / [G, p].  Inactive slots come back
-    bit-identical with NULL/zero path rows.
+    `arena` is a PackedTree whose leaves carry a leading [G] axis, passed
+    to the kernel as it is stored; `active` is a [G] mask (bool or i32).
+    Returns (edge_VL', node_O', path_nodes, path_actions, depths, leaves):
+    the two statistics packed as the arena stores them, updated in place,
+    the paths [G, p, D] / [G, p].  Inactive slots come back bit-identical
+    with NULL/zero path rows.
     """
-    Fp, D = cfg.Fp, cfg.D
-    G, X = arena.child.shape[0], arena.child.shape[1]
-    child_p = cm.pack_edges_arena(arena.child, Fp)
-    en_p = cm.pack_edges_arena(arena.edge_N, Fp)
-    ew_p = cm.pack_edges_arena(arena.edge_W, Fp)
-    ep_p = cm.pack_edges_arena(arena.edge_P, Fp)
-    evl_p = cm.pack_edges_arena(arena.edge_VL, Fp)
-    nn_p = cm.pack_nodes_arena(arena.node_N)
-    no_p = cm.pack_nodes_arena(arena.node_O)
-    ne_p = cm.pack_nodes_arena(arena.num_expanded)
-    na_p = cm.pack_nodes_arena(arena.num_actions)
-    tm_p = cm.pack_nodes_arena(arena.terminal)
-    lg_p = cm.pack_nodes_arena(arena.log_table)
+    D = cfg.D
+    G, er, _ = arena.child.shape
+    nr, lr = arena.node_N.shape[1], arena.log_table.shape[1]
     meta = jnp.stack(
         [jnp.asarray(arena.root, jnp.int32),
          jnp.asarray(arena.size, jnp.int32),
          jnp.asarray(active, jnp.int32)], axis=1)          # [G, 3]
 
-    er, nr, lr = child_p.shape[1], nn_p.shape[1], lg_p.shape[1]
     slot = lambda *shp: pl.BlockSpec((None,) + shp,
                                      lambda g, m: (g,) + (0,) * len(shp))
     out_shapes = (
@@ -236,11 +226,10 @@ def select_arena(cfg: TreeConfig, arena, active, p: int, interpret: bool):
         out_shape=out_shapes,
         input_output_aliases={10: 0, 11: 1},
         name="select_arena",   # the op name traces and readers match
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=cm.VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(meta, child_p, en_p, ew_p, ep_p, nn_p, ne_p, na_p, tm_p, lg_p,
-      evl_p, no_p)
-    return (
-        cm.unpack_edges_arena(evl2, X, Fp),
-        cm.unpack_nodes_arena(no2, X),
-        pn, pa, dep[:, 0], leaf[:, 0],
-    )
+    )(meta, arena.child, arena.edge_N, arena.edge_W, arena.edge_P,
+      arena.node_N, arena.num_expanded, arena.num_actions, arena.terminal,
+      arena.log_table, arena.edge_VL, arena.node_O)
+    return evl2, no2, pn, pa, dep[:, 0], leaf[:, 0]

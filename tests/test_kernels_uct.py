@@ -10,7 +10,10 @@ import numpy as np
 import pytest
 
 from repro.core import TreeConfig, init_tree, intree, fixedpoint as fx
-from repro.core.tree import NULL
+from repro.core.tree import (
+    pack_edges, pack_nodes, pack_tree, to_jax, unpack_edges, unpack_nodes,
+    unpack_tree,
+)
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 
@@ -28,7 +31,7 @@ def grow_tree(cfg, supersteps=3, p=6, seed=0):
     """Grow a random-valued tree with the oracle jnp ops to get a
     non-trivial UCT state."""
     rng = np.random.RandomState(seed)
-    tree = init_tree(cfg)
+    tree = to_jax(pack_tree(init_tree(cfg, xp=np)))
     for _ in range(supersteps):
         tree, sel = kref.select_ref(cfg, tree, p)
         tree, new_nodes = intree.insert_batch(cfg, tree, sel)
@@ -58,10 +61,9 @@ def test_select_kernel_matches_ref(cfg, p):
     t_ref, sel_ref = kref.select_ref(cfg, tree, p)
     t_k, sel_k = kops.select_arena(cfg, _arena(tree), jnp.ones(1), p)
     t_k, sel_k = _slot0(t_k), _slot0(sel_k)
-    np.testing.assert_array_equal(np.asarray(t_ref.edge_VL),
-                                  np.asarray(t_k.edge_VL))
-    np.testing.assert_array_equal(np.asarray(t_ref.node_O),
-                                  np.asarray(t_k.node_O))
+    t_ref, t_k = unpack_tree(t_ref), unpack_tree(t_k)
+    np.testing.assert_array_equal(t_ref.edge_VL, t_k.edge_VL)
+    np.testing.assert_array_equal(t_ref.node_O, t_k.node_O)
     for f in ("path_nodes", "path_actions", "depths", "leaves",
               "expand_action", "n_insert", "insert_base"):
         np.testing.assert_array_equal(
@@ -86,25 +88,22 @@ def test_backup_kernel_matches_ref(cfg, alternating):
     t_k = _slot0(kops.backup_arena(
         cfg, _arena(tree), jnp.ones(1), _arena(sel), sim_nodes[None],
         vals[None], alternating))
+    t_ref, t_k = unpack_tree(t_ref), unpack_tree(t_k)
     for f in ("edge_N", "edge_W", "edge_VL", "node_N", "node_O"):
         np.testing.assert_array_equal(
-            np.asarray(getattr(t_ref, f)), np.asarray(getattr(t_k, f)),
-            err_msg=f)
+            getattr(t_ref, f), getattr(t_k, f), err_msg=f)
 
 
 def test_packing_roundtrip():
-    from repro.kernels import common as cm
     rng = np.random.RandomState(0)
     for x, f in [(64, 2), (100, 4), (48, 36), (128, 128)]:
         fp = 1
         while fp < f:
             fp *= 2
-        arr = jnp.asarray(rng.randint(0, 100, (x, fp)), jnp.int32)
-        packed = cm.pack_edges(arr, fp)
+        arr = rng.randint(0, 100, (x, fp)).astype(np.int32)
+        packed = pack_edges(arr)
         assert packed.shape[1] == 128
-        np.testing.assert_array_equal(
-            np.asarray(cm.unpack_edges(packed, x, fp)), np.asarray(arr))
-        node = jnp.asarray(rng.randint(0, 100, (x,)), jnp.int32)
-        np.testing.assert_array_equal(
-            np.asarray(cm.unpack_nodes(cm.pack_nodes(node), x)),
-            np.asarray(node))
+        np.testing.assert_array_equal(unpack_edges(packed, x, fp), arr)
+        node = rng.randint(0, 100, (x,)).astype(np.int32)
+        np.testing.assert_array_equal(unpack_nodes(pack_nodes(node), x),
+                                      node)

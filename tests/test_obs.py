@@ -333,7 +333,7 @@ def test_fused_run_splits_dispatch_and_commit_into_spans():
     import gc
     import time
 
-    from repro.core.tree import init_arena
+    from repro.core.tree import init_arena, unpack_tree
 
     cl = _fused_client(trace=Tracer(clock_ns=time.process_time_ns),
                        metrics=MetricsRegistry())
@@ -389,8 +389,8 @@ def test_fused_run_splits_dispatch_and_commit_into_spans():
              for site, d in (("upload", "h2d"), ("readback", "d2h"),
                              ("snapshot", "d2h"), ("write", "h2d"))}
     image = FUSED_G * FUSED_CFG.X * 8 * 4      # state (8,) float32
-    slot = sum(a.nbytes for k, a in
-               dataclasses.asdict(init_arena(FUSED_CFG, 1)).items()
+    fresh = unpack_tree(init_arena(FUSED_CFG, 1))
+    slot = sum(a.nbytes for k, a in dataclasses.asdict(fresh).items()
                if k != "log_table")
     dispatches = stats.fused_dispatches
     moves = reg.get("service_moves_committed_total", bucket=label).value

@@ -26,7 +26,7 @@ from repro.core import TreeConfig, make_intree_executor, ref_sequential
 from repro.core.reroot import reroot
 from repro.core.tree import (
     NULL, ROW_FLOOR, ROW_KEYS, UCTree, arena_set_slot, arena_slot,
-    init_tree, row_bucket, to_jax,
+    init_tree, pack_tree, row_bucket, to_jax, unpack_tree,
 )
 from repro.envs import BanditTreeEnv, BanditValueBackend
 from repro.obs.metrics import MetricsRegistry
@@ -51,7 +51,7 @@ def _whole(ex):
         return [_whole(c) for c, _, _ in ex.shards]
     if isinstance(ex.trees, list):
         return [dataclasses.asdict(t.to_tree()) for t in ex.trees]
-    return dataclasses.asdict(jax.device_get(ex.trees))
+    return dataclasses.asdict(unpack_tree(ex.trees))
 
 
 def _assert_same(a, b, label):
@@ -69,7 +69,7 @@ def _old_snapshot(ex, g) -> dict:
     if isinstance(home.trees, list):
         return {k: np.asarray(v) for k, v in
                 dataclasses.asdict(home.trees[r].to_tree()).items()}
-    one = jax.device_get(arena_slot(home.trees, r))
+    one = unpack_tree(arena_slot(home.trees, r))
     return {k: np.asarray(v) for k, v in dataclasses.asdict(one).items()}
 
 
@@ -78,7 +78,7 @@ def _old_set(ex, g, tree: UCTree):
     if isinstance(home.trees, list):
         home.trees[r] = ref_sequential.MutableTree.from_tree(tree)
     else:
-        home.trees = arena_set_slot(home.trees, r, to_jax(tree))
+        home.trees = arena_set_slot(home.trees, r, to_jax(pack_tree(tree)))
 
 
 def _old_write(ex, g, full: dict):
@@ -240,7 +240,7 @@ def test_row_bounded_ops_match_full_slot_path(kind, case):
 # -- the invariant, through the served path -----------------------------
 
 def _assert_rows_initial(pool):
-    trees = jax.device_get(pool.exec.trees)
+    trees = unpack_tree(pool.exec.trees)
     fresh = init_tree(CFG, 0, xp=np)
     for g in range(G):
         size = int(trees.size[g])
@@ -268,7 +268,7 @@ def test_rows_above_size_stay_initial(executor, reuse):
         # its slot and the slot still holds the finished tree
         g = next(g for g, s in enumerate(pool.slots)
                  if s is not None and s.req.uid == res.uid)
-        want = jax.device_get(arena_slot(pool.exec.trees, g))
+        want = unpack_tree(arena_slot(pool.exec.trees, g))
         for k, v in dataclasses.asdict(want).items():
             np.testing.assert_array_equal(res.tree_snapshot[k], v,
                                           err_msg=f"keep_tree {k}")
@@ -280,7 +280,7 @@ def test_rows_above_size_stay_initial(executor, reuse):
             assert pool.fused_dispatch() > 0
             _assert_rows_initial(pool)
             for g in range(G):
-                want = jax.device_get(arena_slot(pool.exec.trees, g))
+                want = unpack_tree(arena_slot(pool.exec.trees, g))
                 got = pool.exec.slot_snapshot(g)
                 for k, v in dataclasses.asdict(want).items():
                     np.testing.assert_array_equal(got[k], v, err_msg=k)

@@ -24,7 +24,7 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from repro.configs import gomoku_cfg, pong  # noqa: E402
 from repro.core.fused import _fused_program  # noqa: E402
-from repro.core.tree import init_arena  # noqa: E402
+from repro.core.tree import init_arena, stored_rows  # noqa: E402
 from repro.envs import BanditTreeEnv, BanditValueBackend  # noqa: E402
 from repro.kernels import uct_backup, uct_select  # noqa: E402
 
@@ -151,3 +151,19 @@ def test_fused_program_kernel_names_match_the_reader(fused_pong_text):
     assert all(reader.KERNELS.match(n) for n in names), names
     assert sorted(n.split(".")[0] for n in names) == ["backup_arena",
                                                       "select_arena"]
+
+
+def test_fused_program_keeps_the_arena_in_the_kernels_layout(fused_pong_text):
+    """Every arena-sized value of the compiled Pong program is in the
+    row-major layout the two kernels read, and none has the logical
+    [G, X, Fp] / [G, X] shape: the TPU compiler relays nothing out
+    around select and backup (the state image, f32[G, X, 8], is not the
+    arena)."""
+    cfg, G = pong.TREE, 4
+    er, nr, lr = stored_rows(cfg.X, cfg.Fp)
+    arena = re.compile(
+        rf"(s32\[{G},({er}|{nr}),128\]|f32\[{G},{lr},128\])\{{([0-9,]+)")
+    logical = re.compile(rf"s32\[{G},{cfg.X}(,{cfg.Fp})?\]")
+    layouts = {m.group(3) for m in arena.finditer(fused_pong_text)}
+    assert layouts == {"2,1,0"}, layouts
+    assert not logical.search(fused_pong_text)
