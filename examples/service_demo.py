@@ -1,46 +1,37 @@
 """Multi-tree search service demo: many users, one scheduler.
 
-Default mode queues 12 search requests (mixed budgets, some multi-move)
-over a 4-slot tree arena: each superstep advances every occupied slot
-through one Selection / Insertion / Simulation / BackUp round in a
-single device program per phase, with all slots' simulation states fused
-into one backend batch.  Completed searches are evicted and the freed
-slot is immediately refilled from the queue; once the queue drains,
-occupancy decays and the scheduler gathers the active slots into a
-dense, device-resident sub-arena (watch the per-superstep decision
-trace).
+Requests go through the SearchClient handle API — the serving surface
+the paper's narrow CPU<->accelerator interface maps to.  Each superstep
+advances every occupied slot of a pool through one Selection / Insertion
+/ Simulation / BackUp round, with all slots' simulation states fused
+into one backend batch.  Requests carry THREE different TreeConfig shape
+classes and are routed into per-config arena pools by the global
+scheduler under --policy:
 
---client switches to the SearchClient handle API — the serving surface
-the paper's narrow CPU<->accelerator interface maps to.  Requests carry
-THREE different TreeConfig shape classes and are routed into per-config
-arena pools by the global scheduler under --policy:
-
-  round-robin           one pool per tick, rotating (the compat default)
+  round-robin           one pool per tick, rotating (the default)
   weighted-queue-depth  every pool with work advances each tick, deepest
                         backlog first, admission caps proportional to
                         queue-depth share — and the tick's Simulation
                         rows from ALL pools fuse into ONE evaluate()
   deadline-aware        the pool holding the nearest deadline goes first
 
-The client mode streams: each handle's moves() generator yields per-move
+The demo streams: each handle's moves() generator yields per-move
 action/visit-distribution events as the reroots commit (iterating IS
 serving — no drain-to-completion), one request carries a deadline it
 cannot meet (watch it come back "evicted"), and one is cancelled
 mid-flight.  Cold pools retire after --retire-after idle ticks (their
 arena is freed; watch the pool summary) and resurrect on demand.
 
---overlap (client mode) turns on pipelined supersteps: each pool's
-slots are split into --gangs gangs and the superstep is double-buffered
-— gang A's host half (expansion + simulation IPC) runs while gang B's
-device in-tree phases (select -> insert) are already dispatched through
-JAX's async queue.  Results are bit-identical to lock-step; with
+--overlap turns on pipelined supersteps: each pool's slots are split
+into --gangs gangs and the superstep is double-buffered — gang A's host
+half (expansion + simulation IPC) runs while gang B's device in-tree
+phases (select -> insert) are already dispatched through JAX's async
+queue.  Results are bit-identical to lock-step; with
 --trace-out the summary prints how long the main thread blocked on the
 env workers and on the device (the overlap-wait-env /
 overlap-wait-device spans).
 
---frontend keeps the pre-handle ServiceFrontend adapter path.
-
-Observability (client mode): --trace-out records every superstep phase
+Observability: --trace-out records every superstep phase
 (select / expand / simulate / backup / compact-gather / compact-scatter)
 and request lifecycle (submit -> admit -> move commits -> result /
 cancel / evict) on per-pool timelines and writes Chrome-trace JSON;
@@ -55,11 +46,9 @@ the Fig. 8-style breakdown the paper's CPU/FPGA numbers rest on.
 
   PYTHONPATH=src python examples/service_demo.py
   PYTHONPATH=src python examples/service_demo.py --executor pallas
-  PYTHONPATH=src python examples/service_demo.py --frontend
-  PYTHONPATH=src python examples/service_demo.py --client
-  PYTHONPATH=src python examples/service_demo.py --client \
+  PYTHONPATH=src python examples/service_demo.py \
       --policy weighted-queue-depth --trace-out trace.json --metrics
-  PYTHONPATH=src python examples/service_demo.py --client --overlap \
+  PYTHONPATH=src python examples/service_demo.py --overlap \
       --expansion pool --gangs 2
 """
 
@@ -71,10 +60,7 @@ import numpy as np
 from repro.core import TreeConfig
 from repro.envs import BanditTreeEnv, BanditValueBackend
 from repro.launch.compile_cache import use_compile_cache
-from repro.service import (
-    POLICY_NAMES, SearchClient, SearchRequest, SearchService,
-    ServiceFrontend,
-)
+from repro.service import POLICY_NAMES, SearchClient, SearchRequest
 
 CFGS = (TreeConfig(X=512, F=6, D=8),    # deep, big arena
         TreeConfig(X=256, F=6, D=6),    # mid
@@ -179,45 +165,6 @@ def run_client(args):
     client.close()
 
 
-def run_frontend(args):
-    """Heterogeneous-config serving through the pre-handle adapter."""
-    env = BanditTreeEnv(fanout=6, terminal_depth=12)
-    fe = ServiceFrontend(
-        env, BanditValueBackend(), G=4, p=16,
-        executor=args.executor, expansion=args.expansion,
-        policy=args.policy,
-        compact_threshold=0.5, compact_exit_threshold=0.75,
-        supersteps_per_dispatch=args.supersteps_per_dispatch,
-    )
-    for i in range(12):
-        fe.submit(SearchRequest(
-            uid=i, seed=i, budget=6 + 2 * (i % 4), moves=1 if i % 3 else 2,
-            cfg=CFGS[i % len(CFGS)],        # mixed shape classes
-        ))
-    while fe.superstep():
-        pool = fe.pools[fe.last_key]
-        d = pool.last_decision
-        mode = (f"session[{d['session']}] sub-arena G={d['G_exec']}"
-                if d["compacted"] else "masked full arena")
-        print(f"tick {fe.stats.ticks:3d}: "
-              f"bucket X={pool.cfg.X} D={pool.cfg.D} "
-              f"{pool.load()}/{pool.G} slots active — {mode}")
-    for r in sorted(fe.completed, key=lambda r: r.uid):
-        print(f"req {r.uid:2d}: actions={r.actions} "
-              f"reward={sum(r.rewards):+.3f} supersteps={r.supersteps}")
-    print()
-    for ps in fe.pool_summaries():
-        print(f"bucket {ps['bucket'][:3]}: {ps['completed']} done in "
-              f"{ps['supersteps']} supersteps; sessions: "
-              f"{ps['session_gathers']} gathers / "
-              f"{ps['session_reuses']} resident reuses / "
-              f"{ps['session_scatters']} scatters")
-    s = fe.stats
-    print(f"\n{s.completed} searches over {len(fe.pools)} config buckets "
-          f"in {s.supersteps} supersteps on executor={args.executor}")
-    fe.close()
-
-
 def main():
     use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
@@ -231,9 +178,9 @@ def main():
                          "one flattened step_batch across all slots "
                          "(vector), or a process pool of scalar workers")
     ap.add_argument("--policy", choices=POLICY_NAMES, default="round-robin",
-                    help="global schedule policy (client/frontend modes): "
-                         "which pools advance each tick and how buckets "
-                         "admit; weighted-queue-depth gang ticks fuse ONE "
+                    help="global schedule policy: which pools advance "
+                         "each tick and how buckets admit; "
+                         "weighted-queue-depth gang ticks fuse ONE "
                          "evaluate() batch across every pool")
     ap.add_argument("--supersteps-per-dispatch", type=int, default=1,
                     metavar="K",
@@ -244,91 +191,33 @@ def main():
                          "bandit env here has them; host-only backends "
                          "silently keep the K=1 phase-by-phase path)")
     ap.add_argument("--overlap", action="store_true",
-                    help="client mode: pipelined supersteps — split each "
-                         "pool's slots into --gangs gangs and double-"
-                         "buffer the superstep, so one gang's host "
+                    help="pipelined supersteps — split each pool's "
+                         "slots into --gangs gangs and double-buffer the "
+                         "superstep, so one gang's host "
                          "expansion/simulation runs while the next gang's "
                          "device in-tree phases are already dispatched "
                          "(results stay bit-identical; disables "
                          "compaction, which needs slot rows to stay put)")
     ap.add_argument("--gangs", type=int, default=2, metavar="N",
-                    help="client mode: gangs per pool for --overlap "
-                         "(2 = classic double buffering)")
+                    help="gangs per pool for --overlap (2 = classic "
+                         "double buffering)")
     ap.add_argument("--shards", type=int, default=1, metavar="D",
-                    help="client mode: partition each bucket's G slots "
-                         "across D per-device shard arenas (least-loaded "
+                    help="partition each bucket's G slots across D "
+                         "per-device shard arenas (least-loaded "
                          "placement; results bit-identical to D=1).  Use "
                          "XLA_FLAGS=--xla_force_host_platform_device_count"
                          "=D for real per-shard devices on a CPU host")
     ap.add_argument("--retire-after", type=int, default=12, metavar="TICKS",
-                    help="client mode: idle ticks before a cold pool "
-                         "releases its arena (resurrected on demand)")
+                    help="idle ticks before a cold pool releases its "
+                         "arena (resurrected on demand)")
     ap.add_argument("--trace-out", metavar="PATH", default=None,
-                    help="client mode: record phase + request-lifecycle "
-                         "spans and write Chrome-trace JSON here (open at "
+                    help="record phase + request-lifecycle spans and "
+                         "write Chrome-trace JSON here (open at "
                          "ui.perfetto.dev)")
     ap.add_argument("--metrics", action="store_true",
-                    help="client mode: print the Prometheus exposition "
-                         "snapshot of the scheduler/pool telemetry")
-    ap.add_argument("--client", action="store_true",
-                    help="serve through the SearchClient handle API: "
-                         "streamed moves(), priorities, deadlines, "
-                         "cancellation, cold-pool retirement")
-    ap.add_argument("--frontend", action="store_true",
-                    help="serve a heterogeneous-config mix through the "
-                         "pre-handle ServiceFrontend adapter")
-    args = ap.parse_args()
-    if args.client:
-        return run_client(args)
-    if args.frontend:
-        return run_frontend(args)
-
-    env = BanditTreeEnv(fanout=6, terminal_depth=12)
-    cfg = TreeConfig(X=512, F=6, D=8)
-    svc = SearchService(
-        cfg, env, BanditValueBackend(),
-        G=4,                     # concurrent tree slots
-        p=16,                    # workers (simulations) per tree per superstep
-        executor=args.executor,  # unified stack ("reference" = numpy oracle)
-        compact_threshold=0.5,   # opt-in: gather active slots when <= half
-        expansion=args.expansion,  # batched host expansion (core.expand)
-        supersteps_per_dispatch=args.supersteps_per_dispatch,
-    )                            # the arena is occupied (see pool docs)
-
-    for i in range(12):
-        svc.submit(SearchRequest(
-            uid=i,
-            seed=i,
-            budget=6 + 2 * (i % 4),        # mixed budgets: slots drain
-            moves=1 if i % 3 else 2,       # unevenly, so the tail of the
-        ))                                 # run exercises compaction
-
-
-    # drive dispatch-by-dispatch to trace the occupancy/compaction choice
-    # (a fused dispatch runs up to K supersteps per compiled program)
-    K = args.supersteps_per_dispatch
-    while (svc.fused_dispatch() if K > 1 else svc.superstep()):
-        d = svc.last_decision
-        mode = (f"session[{d['session']}] sub-arena G={d['G_exec']}"
-                if d["compacted"] else "masked full arena")
-        print(f"superstep {svc.stats.supersteps:3d}: "
-              f"{svc.load()}/{d['G']} slots active "
-              f"(occupancy {d['occupancy']:.2f}) — {mode}")
-
-    done = svc.completed
-    for r in sorted(done, key=lambda r: r.uid):
-        dist = r.visit_counts[-1]
-        print(f"req {r.uid:2d}: actions={r.actions} "
-              f"reward={sum(r.rewards):+.3f} supersteps={r.supersteps} "
-              f"last visit dist={np.asarray(dist).tolist()}")
-    s = svc.stats
-    print(f"\n{s.completed} searches in {s.supersteps} supersteps "
-          f"on executor={args.executor} "
-          f"({s.compacted_supersteps} compacted, "
-          f"avg occupancy {s.occupancy_sum / max(s.supersteps, 1):.2f}); "
-          f"fused sim batches: {s.sim_batches} "
-          f"(max {s.max_fused_rows} states/batch); "
-          f"intree={s.t_intree:.3f}s host={s.t_host:.3f}s sim={s.t_sim:.3f}s")
+                    help="print the Prometheus exposition snapshot of "
+                         "the scheduler/pool telemetry")
+    run_client(ap.parse_args())
 
 
 if __name__ == "__main__":

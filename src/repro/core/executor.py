@@ -1,19 +1,15 @@
 """Unified in-tree executor stack: one protocol, every backend, any G.
 
-Before this module the repo carried two parallel executor hierarchies:
-single-tree executors in core.mcts (stateless, tree passed in and out,
-Pallas variant included) and arena executors in service.arena (stateful
-over G stacked slots, Pallas gated out because the old kernels managed
-their own grid).  Mirsoleimani et al.'s *Structured Parallel Programming
-for MCTS* argues for exactly one execution abstraction across
-parallelization patterns — this module is that collapse:
+Mirsoleimani et al.'s *Structured Parallel Programming for MCTS* argues
+for exactly one execution abstraction across parallelization patterns;
+this module is that abstraction:
 
   InTreeExecutor        — the protocol.  Every implementation drives G >= 1
                           tree slots through the device phases (Selection /
                           Insertion / finalize / BackUp) under a [G] active
                           mask.  TreeParallelMCTS is the G=1 client,
-                          SearchService the G>1 client; both share this
-                          dispatch instead of duplicating it.
+                          service.pool.ArenaPool the G>1 client; both
+                          share this dispatch instead of duplicating it.
   ReferenceExecutor     — the paper's CPU-only master process: one
                           sequential numpy MutableTree per slot, looped on
                           host.  Correctness oracle and CPU baseline.
@@ -35,16 +31,16 @@ device work (ROADMAP item).  Per-slot arithmetic is position-independent,
 so compaction never changes what a slot computes.
 
 Persistent compaction sessions: the paper's accelerator wins by keeping
-the tree device-resident across supersteps (§IV), and BENCH_service.json
-showed that re-gathering the sub-arena every superstep costs more than
-the masked work it saves.  `open_session` wraps gather/scatter in a
-CompactionSession that keeps the dense sub-arena resident: the gather
-happens once, supersteps accumulate in the sub-executor with
-dirty-tracking, and the scatter back into the full arena is deferred to
-session close or an explicit `sync` (snapshot reads).  Membership
-changes (admission / eviction / reroot rewrites) invalidate the session
-— the pool closes and reopens it — so a stable active set pays one
-gather + one scatter total instead of one per superstep.
+the tree device-resident across supersteps (§IV), and re-gathering the
+sub-arena every superstep costs more than the masked work it saves.
+`open_session` wraps gather/scatter in a CompactionSession that keeps
+the dense sub-arena resident: the gather happens once, supersteps
+accumulate in the sub-executor with dirty-tracking, and the scatter back
+into the full arena is deferred to session close or an explicit `sync`
+(snapshot reads). Membership changes (admission / eviction / reroot
+rewrites) invalidate the session — the pool closes and reopens it — so a
+stable active set pays one gather + one scatter total instead of one per
+superstep.
 """
 
 from __future__ import annotations

@@ -41,8 +41,8 @@ submit time: collect is then a cheap unwrap, and the overlap schedule
 stays legal for every mode.
 
 Both drivers consume this engine: TreeParallelMCTS feeds it one slot,
-service.pool.ArenaPool feeds it every active slot of a superstep (and a
-multi-bucket ServiceFrontend shares ONE engine across all its pools).
+service.pool.ArenaPool feeds it every active slot of a superstep (and
+SchedulerCore shares ONE engine across all its pools).
 """
 
 from __future__ import annotations

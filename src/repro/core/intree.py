@@ -24,8 +24,7 @@ which still serializes BackUp through pipeline stages.
 A `relaxed=True` selection variant applies all virtual loss once per
 superstep *after* all workers choose (single vectorized pass, no serial
 chain).  This is a beyond-paper optimization: it trades the intra-superstep
-worker-repulsion of WU-UCT for a ~p× shorter dependency chain; its effect
-on search diversity is measured in benchmarks/bench_diversity.py.
+worker-repulsion of WU-UCT for a ~p× shorter dependency chain.
 """
 
 from __future__ import annotations
@@ -176,8 +175,7 @@ def select_batch_wavefront(cfg: TreeConfig, tree: PackedTree, p: int):
     repulsion virtual loss provides across a superstep.  Virtual loss / O
     counters are applied once at the end (cross-superstep semantics are
     preserved exactly; intra-superstep repulsion is rank-based instead of
-    VL-based).  Diversity impact vs the faithful path is measured in
-    benchmarks (bench_diversity).
+    VL-based).
     """
     D, X = cfg.D, tree.X
     i32 = jnp.int32

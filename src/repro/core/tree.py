@@ -120,7 +120,7 @@ class TreeConfig:
 
 
 def bucket_key(cfg: TreeConfig) -> tuple:
-    """Canonical arena-pool bucket of a config (service frontend routing).
+    """Canonical arena-pool bucket of a config (SchedulerCore routing).
 
     Two configs share a pool iff every field that can change a slot's bit
     evolution matches.  The only padding that is semantics-free is the
@@ -131,8 +131,8 @@ def bucket_key(cfg: TreeConfig) -> tuple:
     arena.  ``X`` and ``D`` look like shape parameters but are semantic —
     X caps the per-superstep insertion budget and the move-saturation
     check, D caps selection depth — so padding either would break the
-    frontend's bit-identity contract with a dedicated single-config
-    service.
+    client's bit-identity contract with a dedicated single-config
+    ArenaPool.
     """
     return (cfg.X, cfg.Fp, cfg.D, cfg.beta, cfg.vl_mode, cfg.vl_const,
             cfg.score_fn, cfg.leaf_mode, cfg.expand_all)

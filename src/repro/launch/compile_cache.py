@@ -1,6 +1,6 @@
 """Persistent compilation cache placement for the repo's entry points.
 
-Entry points (``chip_smoke.py``, ``examples/*.py``, ``benchmarks/run.py``)
+Entry points (``chip_smoke.py``, ``examples/*.py``, ``perfbench/run.py``)
 call :func:`use_compile_cache` once at start-up; library modules set
 nothing.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself
 and no directory is set here.  Otherwise the cache lives at one fixed

@@ -1,7 +1,5 @@
 """Multi-tree search service: handles, global scheduler, arena pools.
 
-The public API is client-first (new names exported first):
-
   SearchClient / SearchHandle    (client.py)   submit() -> opaque handle
       with done()/result()/cancel()/moves() streaming, poll()/run_until()
       progress — callers never touch pools or arenas.
@@ -13,14 +11,7 @@ The public API is client-first (new names exported first):
       StateTables, queue, and the BSP superstep body (split at the
       Simulation boundary for cross-pool fusion).
 
-Compatibility adapters (deprecated surface, kept working):
-
-  ServiceFrontend (frontend.py)  pre-handle multi-bucket frontend —
-      submit() returns the routed pool; a thin veneer over SearchClient.
-  SearchService   (scheduler.py) the single-bucket service under its
-      historical name (one-time DeprecationWarning).
-  arena-executor aliases         re-exported from core.executor; the
-      repro.service.arena module itself is a lazy deprecation shim.
+Executors are imported from repro.core.executor.
 """
 
 from repro.service.client import SearchClient, SearchHandle
@@ -31,25 +22,11 @@ from repro.service.scheduler_core import (
 from repro.service.pool import (
     ArenaPool, MoveEvent, SearchRequest, SearchResult, ServiceStats,
 )
-from repro.service.frontend import ServiceFrontend
-from repro.service.scheduler import SearchService
-from repro.core.executor import (
-    InTreeExecutor,
-    JaxExecutor as JaxArenaExecutor,
-    PallasExecutor as PallasArenaExecutor,
-    ReferenceExecutor as ReferenceArenaExecutor,
-    make_intree_executor as make_arena_executor,
-)
 
 __all__ = [
-    # new serving API first
     "SearchClient", "SearchHandle",
     "SchedulerCore", "SchedulePolicy", "POLICY_NAMES", "make_policy",
     "RoundRobinPolicy", "WeightedQueueDepthPolicy", "DeadlineAwarePolicy",
     "ArenaPool", "MoveEvent", "SearchRequest", "SearchResult",
     "ServiceStats",
-    # compatibility surface
-    "ServiceFrontend", "SearchService",
-    "InTreeExecutor", "JaxArenaExecutor", "PallasArenaExecutor",
-    "ReferenceArenaExecutor", "make_arena_executor",
 ]

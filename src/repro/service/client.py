@@ -30,8 +30,7 @@ Handles:
 The client itself is a thin veneer: routing, policies, cross-bucket
 admission, deadline eviction, cold-pool retirement and the cross-pool
 fused Simulation batch all live in scheduler_core.SchedulerCore; the
-superstep body lives in pool.ArenaPool.  ServiceFrontend and
-SearchService remain as compatibility adapters over this stack.
+superstep body lives in pool.ArenaPool.
 """
 
 from __future__ import annotations
@@ -159,9 +158,9 @@ class SearchClient:
     """Submit searches, get handles, drive progress — the one public
     entry point of the serving stack.
 
-    Construction mirrors the historical frontends (env + sim + G slots x
-    p workers per bucket, executor/compaction/expansion knobs) and adds
-    the scheduler levers: `policy` (round-robin | weighted-queue-depth |
+    Construction takes env + sim + G slots x p workers per bucket and
+    the executor/compaction/expansion knobs, and adds the scheduler
+    levers: `policy` (round-robin | weighted-queue-depth |
     deadline-aware, or a SchedulePolicy instance), `fuse_across_pools`
     (one evaluate() batch spanning every advancing pool on gang ticks;
     default: whenever the policy gangs), and `retire_after_ticks` (cold
@@ -316,8 +315,7 @@ class SearchClient:
 
     def drain(self, max_ticks: int = 100_000) -> list[SearchResult]:
         """Run every queued/in-flight request to its terminal result and
-        return them all (submission-bucket order) — the compatibility
-        path the frontend adapters drain through."""
+        return them all (submission-bucket order)."""
         return self.core.run(max_ticks)
 
     # ---- views ----

@@ -646,7 +646,7 @@ class ArenaPool:
         return np.array([s is not None for s in self.slots], bool)
 
     def load(self) -> int:
-        """Occupied-slot count — the public load accessor (frontends and
+        """Occupied-slot count — the public load accessor (clients and
         schedulers must not reach into _active)."""
         return int(np.sum(self._active()))
 

@@ -31,9 +31,7 @@ class's BSP superstep body, and everything that spans buckets lives here
 
 Policies:
 
-  round-robin          — one pool per tick, rotating: bit-identical to
-                         the historical ServiceFrontend loop (the
-                         compatibility default).
+  round-robin          — one pool per tick, rotating (the default).
   weighted-queue-depth — a gang tick: every pool with work advances,
                          deepest queue first, with per-bucket admission
                          caps proportional to queue-depth share; the
@@ -98,7 +96,7 @@ class SchedulePolicy:
 
     name = "base"
     #: gang=False advances the FIRST pool in `order` that yields work
-    #: (one superstep per tick — the historical frontend cadence);
+    #: (one superstep per tick — the round-robin cadence);
     #: gang=True advances EVERY pool with work in one tick, which is what
     #: the cross-pool fused evaluate batches over.
     gang = False
@@ -118,7 +116,7 @@ class SchedulePolicy:
 
 
 class RoundRobinPolicy(SchedulePolicy):
-    """One pool per tick, rotating — today's ServiceFrontend behavior."""
+    """One pool per tick, rotating."""
 
     name = "round-robin"
 
@@ -262,8 +260,7 @@ def make_policy(policy: Union[str, SchedulePolicy]) -> SchedulePolicy:
 class SchedulerCore:
     """Config-bucketed arena pools under one global tick clock.
 
-    The engine room of SearchClient (and, through it, the ServiceFrontend
-    / SearchService compatibility adapters).  Owns the pools dict, the
+    The engine room of SearchClient.  Owns the pools dict, the
     policy, the deadline ledger, cold-pool retirement, and the cross-pool
     fused Simulation batch.
     """
@@ -605,8 +602,8 @@ class SchedulerCore:
         pool.completed[:] = keep
 
     def run(self, max_ticks: int = 100_000) -> list[SearchResult]:
-        """Drain every pool (compatibility surface for the adapters; new
-        code drives poll/run_until on the client).  Bounded against the
+        """Drain every pool (SearchClient.drain; handles drive
+        poll/run_until instead).  Bounded against the
         CLOCK, not the call count: a fused dispatch advances `ticks` by
         up to K per tick() call, so counting calls would overshoot the
         budget by a factor of K."""

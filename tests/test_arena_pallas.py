@@ -7,7 +7,7 @@ Three layers of evidence:
      phase (selection / insert / finalize / backup) produces the same
      per-slot trees on reference / faithful / pallas, and inactive slots
      stay bit-frozen;
-  2. SearchService(executor="pallas") end to end equals G independent
+  2. ArenaPool(executor="pallas") end to end equals G independent
      single-tree runs of the numpy oracle (the acceptance claim);
   3. masked vs compacted execution are interchangeable: the same workload
      with compaction disabled and enabled returns identical results while
@@ -20,7 +20,7 @@ import pytest
 from repro.core import TreeConfig, TreeParallelMCTS, make_intree_executor
 from repro.core.tree import NULL
 from repro.envs import BanditTreeEnv, BanditValueBackend
-from repro.service import SearchRequest, SearchService
+from repro.service import ArenaPool, SearchRequest
 
 CFG = TreeConfig(X=256, F=4, D=6)
 CFG_ALL = TreeConfig(X=256, F=4, D=5, score_fn="puct",
@@ -120,12 +120,12 @@ def _single_tree_reference(seed, supersteps):
 
 
 def test_pallas_service_bit_identical_to_numpy_oracle():
-    """Acceptance: SearchService(executor='pallas') end to end — every
+    """Acceptance: ArenaPool(executor='pallas') end to end — every
     slot's tree evolution equals an independent single-tree run of the
     sequential numpy oracle, bit for bit."""
     budget = 5
-    svc = SearchService(CFG, ENV, BanditValueBackend(), G=G, p=P,
-                        executor="pallas")
+    svc = ArenaPool(CFG, ENV, BanditValueBackend(), G=G, p=P,
+                    executor="pallas")
     for i in range(G):
         svc.submit(SearchRequest(uid=i, seed=i, budget=budget,
                                  keep_tree=True))
@@ -146,8 +146,8 @@ def test_compacted_equals_masked(executor):
     threshold run compacts while the disabled run masks — results must be
     bit-identical and the compacted path must actually trigger."""
     def go(thresh):
-        svc = SearchService(CFG, ENV, BanditValueBackend(), G=G, p=P,
-                            executor=executor, compact_threshold=thresh)
+        svc = ArenaPool(CFG, ENV, BanditValueBackend(), G=G, p=P,
+                        executor=executor, compact_threshold=thresh)
         for i in range(3):
             svc.submit(SearchRequest(uid=i, seed=40 + i, budget=3 + 2 * i,
                                      keep_tree=True))

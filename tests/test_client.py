@@ -13,11 +13,9 @@ Claim groups:
     pool's, and fused vs per-pool evaluation is bit-identical;
   * retirement — idle pools release their arena after the TTL and are
     resurrected on demand, preserving every per-request result;
-  * stats / deprecation — the monotonic ticks clock and admission-wait
-    histogram survive aggregation; the legacy surfaces warn once.
+  * stats / exports — the monotonic ticks clock and admission-wait
+    histogram survive aggregation; the package exports one surface.
 """
-
-import warnings
 
 import numpy as np
 import pytest
@@ -26,8 +24,7 @@ from repro.core import TreeConfig
 from repro.core.tree import bucket_key
 from repro.envs import BanditTreeEnv, BanditValueBackend
 from repro.service import (
-    POLICY_NAMES, MoveEvent, SearchClient, SearchRequest, SearchService,
-    ServiceFrontend,
+    POLICY_NAMES, ArenaPool, MoveEvent, SearchClient, SearchRequest,
 )
 
 ENV = BanditTreeEnv(fanout=3, terminal_depth=12)
@@ -241,7 +238,7 @@ def _mix_requests():
 def _dedicated_results():
     out = {}
     for req in _mix_requests():
-        svc = SearchService(req.cfg, ENV, BanditValueBackend(), G=1, p=P)
+        svc = ArenaPool(req.cfg, ENV, BanditValueBackend(), G=1, p=P)
         try:
             svc.submit(SearchRequest(uid=req.uid, seed=req.seed,
                                      budget=req.budget, moves=req.moves))
@@ -789,48 +786,23 @@ def test_pool_load_is_public_and_summaries_use_it():
 
 
 # ---------------------------------------------------------------------------
-# deprecation surface
+# package exports
 # ---------------------------------------------------------------------------
 
-def test_search_service_warns_once_pointing_at_client():
-    SearchService._warned = False
-    with pytest.warns(DeprecationWarning, match="SearchClient"):
-        svc = SearchService(CFG_A, ENV, BanditValueBackend(), G=1, p=P)
-    svc.close()
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")    # second construction is silent
-        SearchService(CFG_A, ENV, BanditValueBackend(), G=1, p=P).close()
-
-
-def test_arena_shim_warns_once_on_legacy_import():
-    import repro.service.arena as arena
-    arena._warned = False
-    with pytest.warns(DeprecationWarning, match="core.executor"):
-        make = arena.make_arena_executor
-    ex = make(CFG_A, 1, "reference")
-    assert ex.G == 1
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        assert arena.JaxArenaExecutor is not None   # silent after first
-    with pytest.raises(AttributeError):
-        arena.not_a_name
-
-
 def test_init_exports_new_names_first():
+    """SearchClient heads the one exported surface; the compatibility
+    names (adapters, executor aliases) and their modules are gone."""
+    import importlib
+
     import repro.service as service
     exported = service.__all__
     assert exported.index("SearchClient") == 0
-    assert exported.index("SearchClient") < exported.index("ServiceFrontend")
-    assert exported.index("SchedulerCore") < exported.index("SearchService")
-
-
-def test_frontend_is_adapter_over_client():
-    fe = ServiceFrontend(ENV, BanditValueBackend(), G=2, p=P,
-                         default_cfg=CFG_A)
-    assert isinstance(fe.client, SearchClient)
-    pool = fe.submit(SearchRequest(uid=0, seed=0, budget=2))
-    assert pool is fe.pools[bucket_key(CFG_A)]
-    (res,) = fe.run()
-    assert res.uid == 0
-    assert fe.stats.ticks == fe.core.ticks
-    fe.close()
+    assert set(exported) == {
+        "SearchClient", "SearchHandle", "SchedulerCore", "SchedulePolicy",
+        "POLICY_NAMES", "make_policy", "RoundRobinPolicy",
+        "WeightedQueueDepthPolicy", "DeadlineAwarePolicy", "ArenaPool",
+        "MoveEvent", "SearchRequest", "SearchResult", "ServiceStats"}
+    assert not hasattr(service, "InTreeExecutor")   # lives in core.executor
+    for mod in ("arena", "frontend", "scheduler"):
+        with pytest.raises(ModuleNotFoundError):
+            importlib.import_module(f"repro.service.{mod}")

@@ -19,16 +19,13 @@ registered executor is enrolled in the whole matrix automatically — a new
 name shows up here (and must declare itself in BIT_COMPATIBLE if it
 claims oracle equality).
 
-The multi-arena frontend rides the same harness: the schedule replayed
-through ServiceFrontend (config-carrying requests, persistent compaction
-sessions) must equal each executor's direct SearchService run — the
-frontend/pool split and session write-back deferral are pure
-re-layerings, never semantic changes.
-
-So does the SearchClient redesign: the same schedule through the handle
-API (round-robin policy — the historical cadence) must round-trip bit-
-identically on every executor, and the cross-pool fused evaluate path
-(weighted-queue-depth gang ticks batching a >= 3-config mix into ONE
+The SearchClient rides the same harness: the schedule submitted through
+the handle API (config-carrying requests, persistent compaction
+sessions, round-robin policy) must equal each executor's direct
+ArenaPool run, drained per handle or through SchedulerCore.run (the
+client/core/pool split and session write-back deferral are pure
+re-layerings, never semantic changes), and the cross-pool fused evaluate
+path (weighted-queue-depth gang ticks batching a >= 3-config mix into ONE
 SimulationBackend.evaluate per tick) must equal dedicated single-config
 runs per request while its fused batches strictly exceed any single
 pool's share.
@@ -40,9 +37,7 @@ import pytest
 from repro.core import TreeConfig
 from repro.core.executor import EXECUTOR_NAMES
 from repro.envs import BanditTreeEnv, BanditValueBackend
-from repro.service import (
-    SearchClient, SearchRequest, SearchService, ServiceFrontend,
-)
+from repro.service import ArenaPool, SearchClient, SearchRequest
 
 CFG = TreeConfig(X=160, F=4, D=6)
 ENV = BanditTreeEnv(fanout=4, terminal_depth=10)
@@ -80,9 +75,9 @@ def _run(executor: str, compact: float, expansion: str):
     key = (executor, compact, expansion)
     if key in _RESULTS:
         return _RESULTS[key]
-    svc = SearchService(CFG, ENV, BanditValueBackend(), G=G, p=P,
-                        executor=executor, compact_threshold=compact,
-                        expansion=expansion)
+    svc = ArenaPool(CFG, ENV, BanditValueBackend(), G=G, p=P,
+                    executor=executor, compact_threshold=compact,
+                    expansion=expansion)
     try:
         for kw in _SCHEDULE:
             svc.submit(SearchRequest(**kw))
@@ -142,54 +137,38 @@ def test_matrix_matches_sequential_oracle(executor, compact, expansion):
 
 
 @pytest.mark.parametrize("executor", EXECUTOR_NAMES)
-def test_frontend_path_matches_direct_service(executor):
-    """The frontend/pool split is a pure re-layering: the same schedule
-    routed through ServiceFrontend (requests carrying their TreeConfig,
-    persistent compaction sessions on) equals the executor's own direct
-    SearchService masked/loop run — and therefore, transitively, the
-    sequential oracle for every BIT_COMPATIBLE executor."""
-    fe = ServiceFrontend(ENV, BanditValueBackend(), G=G, p=P,
-                         executor=executor, compact_threshold=0.7,
-                         persistent_compaction=True)
-    try:
-        for kw in _SCHEDULE:
-            fe.submit(SearchRequest(cfg=CFG, **kw))
-        done = {r.uid: r for r in fe.run()}
-        stats = fe.stats
-    finally:
-        fe.close()
-    assert len(fe.pools) == 1   # one config -> one bucket
-    # the drain tail compacts, and sessions persist across supersteps
-    # instead of re-gathering each one
-    assert stats.compacted_supersteps > 0
-    assert stats.session_gathers < stats.compacted_supersteps
-    assert stats.session_reuses > 0
-    _assert_identical((done, stats.supersteps), _run(executor, 0.0, "loop"),
-                      f"frontend/{executor}")
-
-
-@pytest.mark.parametrize("executor", EXECUTOR_NAMES)
-def test_client_round_trip_matches_direct_service(executor):
+@pytest.mark.parametrize("drain", ["handles", "core.run"])
+def test_client_round_trip_matches_direct_service(executor, drain):
     """Acceptance: the SearchClient handle API (round-robin policy) is a
-    pure re-surfacing — the matrix schedule submitted through handles and
-    drained with result() equals the executor's own direct SearchService
-    masked/loop run, superstep counts included."""
+    pure re-surfacing — the matrix schedule submitted through the client
+    (requests carrying their TreeConfig, persistent compaction sessions
+    on) and drained either with result() on every handle or with
+    SchedulerCore.run equals the executor's own direct ArenaPool
+    masked/loop run, superstep counts included — and therefore,
+    transitively, the sequential oracle for every BIT_COMPATIBLE
+    executor."""
     cl = SearchClient(ENV, BanditValueBackend(), G=G, p=P,
                       executor=executor, default_cfg=CFG,
                       compact_threshold=0.7, persistent_compaction=True)
     try:
         handles = [cl.submit(SearchRequest(cfg=CFG, **kw))
                    for kw in _SCHEDULE]
-        done = {h.uid: h.result() for h in handles}
+        if drain == "handles":
+            done = {h.uid: h.result() for h in handles}
+        else:
+            done = {r.uid: r for r in cl.core.run()}
         stats = cl.stats
     finally:
         cl.close()
     assert all(h.status() == "done" for h in handles)
-    # the compacted drain tail still runs through persistent sessions
+    assert len(cl.core.pools) == 1   # one config -> one bucket
+    # the drain tail compacts, and sessions persist across supersteps
+    # instead of re-gathering each one
     assert stats.compacted_supersteps > 0
     assert stats.session_gathers < stats.compacted_supersteps
+    assert stats.session_reuses > 0
     _assert_identical((done, stats.supersteps), _run(executor, 0.0, "loop"),
-                      f"client/{executor}")
+                      f"client/{drain}/{executor}")
 
 
 # three shape classes for the cross-pool fusion acceptance: same fanout
@@ -216,8 +195,8 @@ def test_client_xpool_fused_matches_dedicated_services(executor):
     finally:
         cl.close()
     for i, kw in enumerate(reqs):
-        svc = SearchService(XPOOL_CFGS[i % 3], ENV, BanditValueBackend(),
-                            G=1, p=P, executor=executor)
+        svc = ArenaPool(XPOOL_CFGS[i % 3], ENV, BanditValueBackend(),
+                        G=1, p=P, executor=executor)
         try:
             svc.submit(SearchRequest(**kw))
             (want,) = svc.run()
@@ -289,8 +268,8 @@ def test_expand_all_vector_matches_loop():
     backend = NNSimBackend(env, init_params(jax.random.PRNGKey(0)))
 
     def go(expansion):
-        svc = SearchService(cfg, env, backend, G=2, p=4, executor="faithful",
-                            alternating_signs=True, expansion=expansion)
+        svc = ArenaPool(cfg, env, backend, G=2, p=4, executor="faithful",
+                        alternating_signs=True, expansion=expansion)
         try:
             for i in range(2):
                 svc.submit(SearchRequest(uid=i, seed=i, budget=3,
@@ -322,9 +301,9 @@ def test_fused_dispatch_matches_oracle(executor, k, compact):
     phase-by-phase path (the degenerate case must not regress); K=4
     must actually run fused dispatches and hit the move-commit escape
     (the schedule's budgets are all < 2K)."""
-    svc = SearchService(CFG, ENV, BanditValueBackend(), G=G, p=P,
-                        executor=executor, compact_threshold=compact,
-                        supersteps_per_dispatch=k)
+    svc = ArenaPool(CFG, ENV, BanditValueBackend(), G=G, p=P,
+                    executor=executor, compact_threshold=compact,
+                    supersteps_per_dispatch=k)
     try:
         for kw in _SCHEDULE:
             svc.submit(SearchRequest(**kw))
@@ -362,8 +341,8 @@ def test_fused_dispatch_expansion_escape_matches_oracle(executor):
     env = _PartialDeviceEnv(fanout=4, terminal_depth=10)
 
     def go(executor, k):
-        svc = SearchService(CFG, env, BanditValueBackend(), G=G, p=P,
-                            executor=executor, supersteps_per_dispatch=k)
+        svc = ArenaPool(CFG, env, BanditValueBackend(), G=G, p=P,
+                        executor=executor, supersteps_per_dispatch=k)
         try:
             for kw in _SCHEDULE:
                 svc.submit(SearchRequest(**kw))

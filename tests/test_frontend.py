@@ -5,11 +5,11 @@ Three claim groups:
 
   * bucketing — core.tree.bucket_key groups configs iff nothing that can
     change a slot's bit evolution differs (fanout padding to Fp is the
-    one semantics-free merge), and ServiceFrontend routes each request to
+    one semantics-free merge), and SearchClient routes each request to
     the pool of its bucket;
   * routing bit-identity (acceptance) — a heterogeneous request mix
-    through the frontend produces, per request, results bit-identical to
-    a dedicated single-config SearchService run of that request, for
+    through the client produces, per request, results bit-identical to
+    a dedicated single-config ArenaPool run of that request, for
     EVERY executor in EXECUTOR_NAMES;
   * sessions (acceptance) — with persistent compaction and a stable
     active set the sub-arena is gathered once and re-gathered only on
@@ -25,9 +25,7 @@ from repro.core import TreeConfig
 from repro.core.executor import EXECUTOR_NAMES
 from repro.core.tree import bucket_key, canonical_config
 from repro.envs import BanditTreeEnv, BanditValueBackend
-from repro.service import (
-    ArenaPool, SearchRequest, SearchService, ServiceFrontend,
-)
+from repro.service import ArenaPool, SearchClient, SearchRequest
 
 ENV = BanditTreeEnv(fanout=3, terminal_depth=10)
 P = 4
@@ -68,31 +66,34 @@ def test_canonical_config_is_bucket_representative():
 
 
 def test_frontend_routes_by_bucket():
-    fe = ServiceFrontend(ENV, BanditValueBackend(), G=2, p=P)
-    pools = [fe.submit(SearchRequest(uid=i, seed=i, budget=2, cfg=cfg))
-             for i, cfg in enumerate(MIX)]
-    assert len(fe.pools) == 2
+    cl = SearchClient(ENV, BanditValueBackend(), G=2, p=P)
+    pools = []
+    for i, cfg in enumerate(MIX):
+        req = SearchRequest(uid=i, seed=i, budget=2, cfg=cfg)
+        cl.submit(req)
+        pools.append(cl.core.pools[bucket_key(req.cfg)])
+    assert len(cl.core.pools) == 2
     assert pools[0] is pools[2] is pools[3]                # CFG_A bucket
     assert pools[1] is pools[4]                            # CFG_B bucket
     assert pools[0] is not pools[1]
-    fe.run()
-    fe.close()
+    cl.core.run()
+    cl.close()
 
 
 def test_frontend_requires_some_config():
-    fe = ServiceFrontend(ENV, BanditValueBackend(), G=2, p=P)
+    cl = SearchClient(ENV, BanditValueBackend(), G=2, p=P)
     with pytest.raises(ValueError, match="no TreeConfig"):
-        fe.submit(SearchRequest(uid=0, seed=0))
-    fe.close()
+        cl.submit(SearchRequest(uid=0, seed=0))
+    cl.close()
 
 
 def test_default_cfg_serves_bare_requests():
-    fe = ServiceFrontend(ENV, BanditValueBackend(), G=2, p=P,
-                         default_cfg=CFG_A)
-    fe.submit(SearchRequest(uid=0, seed=0, budget=2))
-    (res,) = fe.run()
+    cl = SearchClient(ENV, BanditValueBackend(), G=2, p=P,
+                      default_cfg=CFG_A)
+    cl.submit(SearchRequest(uid=0, seed=0, budget=2))
+    (res,) = cl.core.run()
     assert res.uid == 0 and res.actions
-    fe.close()
+    cl.close()
 
 
 def test_pool_rejects_foreign_config():
@@ -127,22 +128,22 @@ def _assert_result_equal(got, want, label):
 @pytest.mark.parametrize("executor", EXECUTOR_NAMES)
 def test_heterogeneous_mix_matches_dedicated_services(executor):
     """Acceptance: every request of a mixed-config batch through the
-    frontend is bit-identical to the same request on a dedicated
-    single-config SearchService of its own (unpadded) config."""
-    fe = ServiceFrontend(ENV, BanditValueBackend(), G=2, p=P,
-                         executor=executor, compact_threshold=0.6,
-                         persistent_compaction=True)
+    client is bit-identical to the same request on a dedicated
+    single-config ArenaPool of its own (unpadded) config."""
+    cl = SearchClient(ENV, BanditValueBackend(), G=2, p=P,
+                      executor=executor, compact_threshold=0.6,
+                      persistent_compaction=True)
     try:
         for req in _mix_requests():
-            fe.submit(req)
-        done = {r.uid: r for r in fe.run()}
+            cl.submit(req)
+        done = {r.uid: r for r in cl.core.run()}
     finally:
-        fe.close()
+        cl.close()
     assert sorted(done) == list(range(len(MIX)))
 
     for req in _mix_requests():
-        svc = SearchService(req.cfg, ENV, BanditValueBackend(), G=1, p=P,
-                            executor=executor)
+        svc = ArenaPool(req.cfg, ENV, BanditValueBackend(), G=1, p=P,
+                        executor=executor)
         try:
             svc.submit(SearchRequest(uid=req.uid, seed=req.seed,
                                      budget=req.budget, moves=req.moves,
@@ -161,9 +162,9 @@ def test_heterogeneous_mix_matches_dedicated_services(executor):
 def _low_occupancy_service(executor="faithful", persistent=True, **kw):
     # G=4 with a single active slot: always below the enter threshold,
     # so every superstep runs on the (gathered or resident) sub-arena
-    return SearchService(CFG_A, ENV, BanditValueBackend(), G=4, p=P,
-                         executor=executor, compact_threshold=0.5,
-                         persistent_compaction=persistent, **kw)
+    return ArenaPool(CFG_A, ENV, BanditValueBackend(), G=4, p=P,
+                     executor=executor, compact_threshold=0.5,
+                     persistent_compaction=persistent, **kw)
 
 
 @pytest.mark.parametrize("executor", ["reference", "faithful"])
@@ -243,9 +244,9 @@ def test_persistent_sessions_bit_identical_to_per_superstep(executor):
     """Sessions are a pure cost optimization: deferring the scatter can
     never change what any slot computes."""
     def go(persistent):
-        svc = SearchService(CFG_A, ENV, BanditValueBackend(), G=4, p=P,
-                            executor=executor, compact_threshold=0.6,
-                            persistent_compaction=persistent)
+        svc = ArenaPool(CFG_A, ENV, BanditValueBackend(), G=4, p=P,
+                        executor=executor, compact_threshold=0.6,
+                        persistent_compaction=persistent)
         try:
             for i in range(3):
                 svc.submit(SearchRequest(uid=i, seed=30 + i,
@@ -269,9 +270,9 @@ def test_hysteresis_thresholds_stop_decision_thrash():
     the compacted decision stable; with exit == enter (default) the same
     oscillation flips the decision every tick."""
     def decisions(enter, exit_, As):
-        svc = SearchService(CFG_A, ENV, BanditValueBackend(), G=8, p=P,
-                            compact_threshold=enter,
-                            compact_exit_threshold=exit_)
+        svc = ArenaPool(CFG_A, ENV, BanditValueBackend(), G=8, p=P,
+                        compact_threshold=enter,
+                        compact_exit_threshold=exit_)
         out = []
         for a in As:
             active = np.zeros(8, bool)
@@ -292,5 +293,5 @@ def test_hysteresis_thresholds_stop_decision_thrash():
 
 def test_hysteresis_exit_below_enter_rejected():
     with pytest.raises(AssertionError, match="hysteresis"):
-        SearchService(CFG_A, ENV, BanditValueBackend(), G=4, p=P,
-                      compact_threshold=0.5, compact_exit_threshold=0.25)
+        ArenaPool(CFG_A, ENV, BanditValueBackend(), G=4, p=P,
+                  compact_threshold=0.5, compact_exit_threshold=0.25)

@@ -14,9 +14,10 @@ import pytest
 
 from repro.core import TreeConfig, TreeParallelMCTS
 from repro.envs import BanditTreeEnv, BanditValueBackend
-from repro.service import (
-    JaxArenaExecutor, SearchRequest, SearchService,
+from repro.core.executor import (
+    JaxExecutor, PallasExecutor, make_intree_executor,
 )
+from repro.service import ArenaPool, SearchRequest
 
 CFG = TreeConfig(X=256, F=4, D=6)
 ENV = BanditTreeEnv(fanout=4, terminal_depth=10)
@@ -24,8 +25,8 @@ P = 8
 
 
 def _service(G, executor="faithful", **kw):
-    return SearchService(CFG, ENV, BanditValueBackend(), G=G, p=P,
-                         executor=executor, **kw)
+    return ArenaPool(CFG, ENV, BanditValueBackend(), G=G, p=P,
+                     executor=executor, **kw)
 
 
 def _single_tree_reference(seed, supersteps):
@@ -128,8 +129,8 @@ def test_multi_move_flush_fallback_matches_fresh_searches():
         def initial_state(self, seed):
             return s1
 
-    svc2 = SearchService(CFG, _Env(fanout=4, terminal_depth=10),
-                         BanditValueBackend(), G=1, p=P, executor="faithful")
+    svc2 = ArenaPool(CFG, _Env(fanout=4, terminal_depth=10),
+                     BanditValueBackend(), G=1, p=P, executor="faithful")
     svc2.submit(SearchRequest(uid=1, seed=0, budget=4))
     (res2,) = svc2.run()
     assert res.actions[1] == res2.actions[0]
@@ -179,8 +180,8 @@ def test_expand_all_puct_service_runs():
     cfg = TreeConfig(X=128, F=36, D=5, beta=5.0, score_fn="puct",
                      leaf_mode="unexpanded", expand_all=True)
     backend = NNSimBackend(env, init_params(jax.random.PRNGKey(0)))
-    svc = SearchService(cfg, env, backend, G=2, p=4, executor="faithful",
-                        alternating_signs=True)
+    svc = ArenaPool(cfg, env, backend, G=2, p=4, executor="faithful",
+                    alternating_signs=True)
     for i in range(2):
         svc.submit(SearchRequest(uid=i, seed=i, budget=3, keep_tree=True))
     done = svc.run()
@@ -196,9 +197,8 @@ def test_pallas_is_first_class_arena_executor():
     """The arena-native kernels serve the arena directly: "pallas" is its
     own executor in the unified stack (not a JaxExecutor variant, which
     still rejects the name — the jit and kernel paths stay distinct)."""
-    from repro.service import PallasArenaExecutor, make_arena_executor
-    ex = make_arena_executor(CFG, 2, "pallas")
-    assert isinstance(ex, PallasArenaExecutor)
+    ex = make_intree_executor(CFG, 2, "pallas")
+    assert isinstance(ex, PallasExecutor)
     assert ex.G == 2
     with pytest.raises(NotImplementedError):
-        JaxArenaExecutor(CFG, 2, variant="pallas")
+        JaxExecutor(CFG, 2, variant="pallas")
